@@ -39,7 +39,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def _setup_devices(virtual: int):
     if virtual:
-        # Shared anti-sitecustomize recipe (repo-root cpuforce.py); only
+        # Shared CPU-forcing recipe (repo-root cpuforce.py); only
         # effective if the jax backend is not yet initialized.
         from cpuforce import force_cpu
 

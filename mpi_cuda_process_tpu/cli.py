@@ -386,15 +386,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "step, and endpoint handlers never touch the "
                         "run loop.  Shuts down with the run")
     p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="JAX persistent compilation cache directory: "
-                        "compiled executables are written to DIR and "
-                        "reloaded on later runs, so a program shape "
-                        "this machine has EVER compiled (any process) "
-                        "skips the real XLA backend work.  The serving "
-                        "engine points every size-class build here so "
-                        "even a cold class almost never pays a cold "
-                        "compile.  Lifecycle-only: the cache changes "
-                        "when a run compiles, never what it computes")
+                   help="JAX persistent compilation cache directory "
+                        "(default <checkout>/.jax_cache).  Ignored when "
+                        "JAX_COMPILATION_CACHE_DIR is set: that "
+                        "directory wins.  The cache changes when a run "
+                        "compiles, never what it computes")
     p.add_argument("--serve-engine", type=int, default=None,
                    metavar="PORT",
                    help="resident serving engine (serving/): run this "
@@ -528,7 +524,7 @@ def config_from_args(argv=None) -> RunConfig:
     )
 
 
-# Measured on the real v5e chip, round 3 (benchmarks/results_r03.json):
+# Measured on the real v5e chip, round 3 (2026-07-29..31 campaign, ROADMAP A):
 # the whole-step raw Pallas kernels (ops/pallas/rawstep.py) beat XLA's
 # fusion for these stencils at every size (heat3d27 raw 37.6 vs jnp 21.4;
 # wave3d raw 23.9 vs jnp 13.4; grayscott3d raw 22.7 vs jnp 14.4).  The
@@ -543,7 +539,7 @@ _CLIFF_CELLS = 100_000_000
 
 # Transparent temporal blocking (ops/pallas/fused.py), k steps per HBM
 # pass: the fastest measured path at every size for these families
-# (results_r03.json, f32 Gcells/s fused vs best-other):
+# (2026-07-29..31 campaign, f32 Gcells/s fused vs best-other):
 #   heat3d    107.0 / 107.3  vs jnp  86.3 (256^3) /  17.6 (512^3)
 #   heat3d27   50.4 /  47.8  vs raw  37.6         /  38.5
 #   wave3d     70.0 /  71.1  vs raw  23.9         /  23.8
@@ -597,8 +593,8 @@ def maybe_auto_fuse(cfg: RunConfig) -> RunConfig:
     this is purely an execution-strategy choice.  Only taken when every
     cadence (iters, log/checkpoint/dump/check-finite intervals) is a
     multiple of k, nothing about the run observes individual steps, and the
-    grid is tileable; a compile failure on the real chip is caught by
-    ``run``'s auto-retry, which re-runs the whole config on the jnp path.
+    grid is tileable (the builder returns None when it is not).  A kernel
+    that then fails to compile or run raises its own error.
     """
     if cfg.compute != "auto" or cfg.fuse:
         return cfg
@@ -798,9 +794,13 @@ def build(cfg: RunConfig):
             periodic=cfg.periodic, ensemble=cfg.ensemble,
             perturb=cfg.ensemble_perturb)
     else:
-        fields = init_state(st, cfg.grid, cfg.seed, cfg.density, cfg.init,
-                            periodic=cfg.periodic, ensemble=cfg.ensemble,
-                            perturb=cfg.ensemble_perturb)
+        # one jitted program: XLA fuses draw, cast and frame pin into one
+        # pass — eagerly each op holds a full-grid temporary, and 1024^3
+        # f32 ran out of the chip's 16 GiB (PR 21)
+        fields = jax.jit(functools.partial(
+            init_state, st, cfg.grid, cfg.seed, cfg.density, cfg.init,
+            periodic=cfg.periodic, ensemble=cfg.ensemble,
+            perturb=cfg.ensemble_perturb))()
     if cfg.fuse_kind != "auto" and not cfg.fuse:
         # a forced kind with auto-selected fuse would route maybe_auto_fuse
         # upgrades into a kernel that was never probed (and silently no-op
@@ -1048,12 +1048,8 @@ def _epilogue(cfg: RunConfig, fields, final_step: int, save_ckpt: bool,
 def run(cfg: RunConfig) -> Tuple:
     """Execute a configured run; returns (final_fields, mcells_per_s).
 
-    ``--compute auto`` has a no-crash guarantee on the Pallas paths: if the
-    auto-selected kernel (temporal blocking or the raw whole-step kernel)
-    fails to compile or run on the real chip, the whole config is re-run on
-    the jnp path with a warning — ``auto`` never turns a valid config into
-    a JaxRuntimeError (round-2 verdict: ``_PALLAS_WINS`` used to route
-    heat3d27 straight into a compile failure).
+    An auto-selected Pallas path that fails to compile or run raises its
+    own error: no config is silently re-run on another path.
     """
     if cfg.serve_port is not None and not cfg.telemetry:
         # --serve tails the telemetry log; without one there is nothing
@@ -1096,107 +1092,41 @@ def run(cfg: RunConfig) -> Tuple:
                  f"{decision.value} {decision.unit}"
                  if decision.value is not None else "no ranked candidate")
     fused_cfg = cfg if decision is not None else maybe_auto_fuse(cfg)
-    # "Did auto actually pick a Pallas path?" — not just eligibility: the
-    # raw-step builder can decline (untileable shape), in which case the run
-    # is pure jnp and a failure there must surface, not trigger a pointless
-    # identical re-run.
-    auto_pallas = fused_cfg.fuse != cfg.fuse
-    if decision is not None and cfg.fuse and \
-            "fuse" not in decision.overrides:
-        # the POLICY picked the fused path, not the user: the no-crash
-        # guarantee covers it exactly like a maybe_auto_fuse upgrade
-        auto_pallas = True
-    if not auto_pallas and cfg.compute == "auto" and \
-            _raw_eligible(cfg, cfg.stencil):
-        auto_pallas = resolve_raw_step(cfg, _make_cfg_stencil(cfg)) is not None
-    try:
-        return _run_once(fused_cfg, decision=decision)
-    except Exception as e:  # noqa: BLE001 — Pallas failures surface as
-        # JaxRuntimeError at execute time but as plain ValueError /
-        # NotImplementedError / lowering errors at trace time; the no-crash
-        # guarantee must cover both.  Non-Pallas runs re-raise untouched,
-        # and a genuine config/runtime error raises identically from the
-        # jnp retry.
-        if not auto_pallas or not _looks_like_pallas_failure(e):
-            raise
-        first = str(e).splitlines()[0][:160] if str(e) else type(e).__name__
-        log.warning(
-            "auto-selected Pallas path failed (%s); retrying this run on "
-            "the jnp path", first)
-        retry_cfg = dataclasses.replace(cfg, compute="jnp")
-        if decision is not None and retry_cfg.fuse and \
-                "fuse" not in decision.overrides:
-            # a policy-chosen fused mode keeps its kernel on the jnp
-            # retry config unless cleared — strip the Pallas-only modes
-            # back to the plain path the retry is promising
-            retry_cfg = dataclasses.replace(
-                retry_cfg, fuse=0, fuse_kind="auto", pipeline=False,
-                exchange="ppermute", kernel_variant="")
-        if cfg.telemetry:
-            # keep the failed run's trace (it recorded the error event);
-            # the retry writes its own log next to it
-            retry_cfg = dataclasses.replace(
-                retry_cfg, telemetry=cfg.telemetry + ".retry.jsonl")
-        return _run_once(retry_cfg, decision=decision)
+    return _run_once(fused_cfg, decision=decision)
 
 
-def _looks_like_pallas_failure(e: BaseException) -> bool:
-    """Did this failure originate in the kernel stack (worth a jnp retry)?
+# The checkout-local default of the persistent compilation cache: a fixed
+# path (git-ignored), since the path is part of the cache's key.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
-    A genuine user/config error inside an auto-Pallas run used to cost a
-    full (possibly long) jnp re-run before surfacing identically (round-3
-    verdict weak #6).  Two signals, either sufficient: a frame of the
-    traceback lives in the Pallas/Mosaic stack, or the message carries a
-    compile/runtime marker of the kernel path.  When neither fires the
-    error is re-raised immediately.
+
+def enable_compile_cache(directory=None):
+    """Turn on jax's persistent compilation cache; returns its directory.
+
+    Placement: ``JAX_COMPILATION_CACHE_DIR`` when set (jax reads it
+    itself, and no other directory is set here); else ``directory``
+    (``--compile-cache DIR``); else :data:`DEFAULT_COMPILE_CACHE`.  Off
+    (returns None) when ``jax_enable_compilation_cache`` is false, as
+    tests/conftest.py sets it.  The min-compile-time / min-entry-size
+    floors are zeroed so every program lands in the cache.
     """
-    tb = e.__traceback__
-    while tb is not None:
-        fn = tb.tb_frame.f_code.co_filename.replace("\\", "/")
-        if "/ops/pallas/" in fn or "/pallas/" in fn or "mosaic" in fn:
-            return True
-        tb = tb.tb_next
-    msg = f"{type(e).__name__}: {e}"
-    return any(s in msg for s in (
-        "Mosaic", "mosaic", "remote_compile", "RESOURCE_EXHAUSTED",
-        "vmem", "JaxRuntimeError", "XlaRuntimeError", "INTERNAL"))
+    from jax.experimental.compilation_cache import compilation_cache
 
-
-def enable_compile_cache(directory) -> bool:
-    """Point jax's persistent compilation cache at ``directory``.
-
-    Process-wide and idempotent (jax.config.update is last-write-wins).
-    The min-compile-time / min-entry-size floors are zeroed so even the
-    sub-second CPU test programs land in the cache — without that, a
-    tier-1 run would never exercise the read-back path at all.  Returns
-    whether the cache was enabled (best-effort: an old jax without the
-    knobs degrades to a warning, never a crash).
-    """
-    if not directory:
-        return False
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(directory))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:  # noqa: BLE001 — knob absent in older jax
-            pass
-        try:
-            # jax latches "no cache" at the first compile of the
-            # process; a long-lived engine enabling the cache AFTER
-            # some earlier compile must force re-initialization or the
-            # new directory is silently ignored
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private hook; absent is fine
-            pass
-        return True
-    except Exception as e:  # noqa: BLE001 — cache is an optimization
-        log.warning("--compile-cache disabled (%s: %s)",
-                    type(e).__name__, e)
-        return False
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    chosen = env_dir or str(directory or DEFAULT_COMPILE_CACHE)
+    if not env_dir:
+        jax.config.update("jax_compilation_cache_dir", chosen)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax latches "no cache" at the first compile of the process; a
+    # long-lived engine enabling the cache after an earlier compile must
+    # re-initialize it or the directory is ignored
+    compilation_cache.reset_cache()
+    return chosen
 
 
 def _check_mem_budget(cfg: RunConfig) -> None:

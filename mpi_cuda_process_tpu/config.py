@@ -157,10 +157,9 @@ class RunConfig:
     # recorded as a 'serve' event).  Launcher-only: a supervised child
     # must never try to bind the parent's port, so to_argv drops it.
     serve_port: Optional[int] = None
-    # JAX persistent compilation cache directory (--compile-cache DIR):
-    # compiled executables are written to / reloaded from DIR, so a
-    # size class already seen by ANY prior process on this machine
-    # skips the real XLA backend work.  Lifecycle: the cache changes
+    # JAX persistent compilation cache directory (--compile-cache DIR;
+    # JAX_COMPILATION_CACHE_DIR wins, <checkout>/.jax_cache is the
+    # default — cli.enable_compile_cache).  Lifecycle: the cache changes
     # when a program compiles, never what it computes.
     compile_cache: Optional[str] = None
     # resident serving engine (serving/): --serve-engine PORT runs this

@@ -160,6 +160,7 @@ def open_session(
     stall_after_s: float = 600.0,
     with_heartbeat: bool = True,
     ensemble: int = 0,
+    launcher: bool = False,
     **manifest_extra: Any,
 ) -> Session:
     """Open a trace at ``path``, write the manifest, start the heartbeat.
@@ -170,6 +171,8 @@ def open_session(
     (or the spawning thread's pending context) so a supervised child's
     — or an engine request's — spans share the parent's trace_id; the
     manifest carries the ``trace`` identity block either way.
+    ``launcher=True`` (a process that starts chip children) writes a
+    manifest without touching any jax backend.
     """
     trace = trace_lib.TraceWriter(path)
     flight = flightrec_lib.FlightRecorder()
@@ -178,7 +181,7 @@ def open_session(
                                   root_name=tool)
     manifest_extra.setdefault("trace", spans.manifest_block())
     trace.write_manifest(trace_lib.build_manifest(
-        tool, run, **manifest_extra))
+        tool, run, launcher=launcher, **manifest_extra))
     recorder = runtime_lib.RuntimeRecorder(trace=trace, step_unit=step_unit,
                                            ensemble=ensemble, spans=spans)
     hb = None

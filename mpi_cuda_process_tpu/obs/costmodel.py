@@ -36,11 +36,22 @@ import jax.numpy as jnp
 
 from ..utils.jaxprcheck import iter_jaxprs
 
-# v5e anchors (docs/STATE.md): HBM peak per chip; ICI per link.  The
-# measured Mosaic DMA envelope (~330 GB/s) is reported alongside, not
-# substituted — the roofline is an upper bound, not a fit.
-V5E_HBM_GBS = 819.0
-V5E_ICI_GBS = 45.0
+# Published per-chip peaks, keyed by jax's ``device_kind``.  Source:
+# Google Cloud documentation, "TPU v5e": 819 GB/s of HBM bandwidth and
+# 1,600 Gbit/s of chip-to-chip interconnect per chip, priced here per
+# ICI link at 45 GB/s.  A kind not in the table gets no roofline (None):
+# the roofline is an upper bound for a known chip, never a guess.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"hbm_gbs": 819.0, "ici_gbs": 45.0},
+}
+
+
+def device_peaks(kind: Optional[str] = None) -> Optional[Dict[str, float]]:
+    """Peaks of ``kind`` (default: this process's first device), or None
+    when the table does not know it."""
+    if kind is None:
+        kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(kind)
 
 # Elementwise primitives counted as one flop per output element.  A
 # MODEL, not a lowering simulator: comparisons, selects, copies, pads,
@@ -428,8 +439,8 @@ def static_cost(
     fuse_kind: str = "auto",
     periodic: bool = False,
     ensemble: int = 0,
-    hbm_gbs: float = V5E_HBM_GBS,
-    ici_gbs: float = V5E_ICI_GBS,
+    hbm_gbs: Optional[float] = None,
+    ici_gbs: Optional[float] = None,
     exchange: str = "ppermute",
     ensemble_mesh: int = 0,
     variant=None,
@@ -445,7 +456,13 @@ def static_cost(
     ``variant`` (a ``policy.autotune.KernelVariant`` or None) re-pins
     the rdma chunk tables and the traced cross-check under that
     variant's ring geometry — model and kernel read the same constants.
+    ``hbm_gbs``/``ici_gbs`` default to :func:`device_peaks`; on a device
+    the table does not know, ``roofline`` is None.
     """
+    if hbm_gbs is None or ici_gbs is None:
+        peaks = device_peaks() or {}
+        hbm_gbs = peaks.get("hbm_gbs") if hbm_gbs is None else hbm_gbs
+        ici_gbs = peaks.get("ici_gbs") if ici_gbs is None else ici_gbs
     grid = tuple(int(g) for g in grid)
     local = _local_shape(grid, mesh)
     # per-DEVICE members (time-side terms) vs TOTAL members (cell
@@ -460,13 +477,7 @@ def static_cost(
                       variant=variant)
     flops = members * step_flops(stencil, local, periodic=periodic)
     hbm_b = hbm_bytes_per_step(stencil, local, fuse=fuse, batch=members)
-    t_hbm_ms = hbm_b / (hbm_gbs * 1e9) * 1e3
-    t_ici_ms = (comm["ici_bytes_per_step"] / (ici_gbs * 1e9) * 1e3
-                if comm else 0.0)
     cells = total_members * math.prod(grid)
-
-    def _mcells(t_ms: float) -> float:
-        return cells / (t_ms * 1e-3) / 1e6 if t_ms > 0 else float("inf")
 
     out: Dict[str, Any] = {
         "grid": list(grid),
@@ -482,19 +493,9 @@ def static_cost(
         "flops_per_step_per_device": int(flops),
         "hbm_bytes_per_step_per_device": int(hbm_b),
         "comm": comm,
-        "roofline": {
-            "hbm_gbs": hbm_gbs,
-            "ici_gbs": ici_gbs,
-            "predicted_ms_per_step_hbm": round(t_hbm_ms, 6),
-            "predicted_ms_per_step_exchange": round(t_ici_ms, 6),
-            "predicted_mcells_per_s_overlapped": round(
-                _mcells(t_hbm_ms), 1),
-            "predicted_mcells_per_s_serial": round(
-                _mcells(t_hbm_ms + t_ici_ms), 1),
-            "basis": "minimum HBM traffic at peak bandwidth; 'overlapped'"
-                     " assumes the exchange fully hidden (the paper's "
-                     "claim), 'serial' adds it to the critical path",
-        },
+        "roofline": _roofline(cells, hbm_b,
+                              comm["ici_bytes_per_step"] if comm else 0,
+                              hbm_gbs, ici_gbs),
     }
     if comm and comm.get("slab_operand_bytes") is not None:
         try:
@@ -516,8 +517,35 @@ def static_cost(
     return out
 
 
-def coupled_cost(plans, hbm_gbs: float = V5E_HBM_GBS,
-                 ici_gbs: float = V5E_ICI_GBS,
+def _roofline(cells: int, hbm_b: int, ici_b: int,
+              hbm_gbs: Optional[float],
+              ici_gbs: Optional[float]) -> Optional[Dict[str, Any]]:
+    """Throughput bounds from minimum traffic at the peaks; None without
+    peaks (a device :data:`DEVICE_PEAKS` does not know)."""
+    if hbm_gbs is None or ici_gbs is None:
+        return None
+    t_hbm_ms = hbm_b / (hbm_gbs * 1e9) * 1e3
+    t_ici_ms = ici_b / (ici_gbs * 1e9) * 1e3
+
+    def _mcells(t_ms: float) -> float:
+        return cells / (t_ms * 1e-3) / 1e6 if t_ms > 0 else float("inf")
+
+    return {
+        "hbm_gbs": hbm_gbs,
+        "ici_gbs": ici_gbs,
+        "predicted_ms_per_step_hbm": round(t_hbm_ms, 6),
+        "predicted_ms_per_step_exchange": round(t_ici_ms, 6),
+        "predicted_mcells_per_s_overlapped": round(_mcells(t_hbm_ms), 1),
+        "predicted_mcells_per_s_serial": round(
+            _mcells(t_hbm_ms + t_ici_ms), 1),
+        "basis": "minimum HBM traffic at peak bandwidth; 'overlapped'"
+                 " assumes the exchange fully hidden (the paper's "
+                 "claim), 'serial' adds it to the critical path",
+    }
+
+
+def coupled_cost(plans, hbm_gbs: Optional[float] = None,
+                 ici_gbs: Optional[float] = None,
                  transport: str = "") -> Dict[str, Any]:
     """The coupled (``--groups``) run's static cost block.
 
@@ -541,6 +569,8 @@ def coupled_cost(plans, hbm_gbs: float = V5E_HBM_GBS,
     """
     from ..parallel import groups as groups_lib
 
+    if ici_gbs is None:
+        ici_gbs = (device_peaks() or {}).get("ici_gbs")
     transport = transport or groups_lib.TRANSPORT_BACKEND
     group_costs = []
     for p in plans:
@@ -575,7 +605,7 @@ def coupled_cost(plans, hbm_gbs: float = V5E_HBM_GBS,
             "transport": transport,
             "bytes_per_round": int(wire_bytes),
             "staged_bytes_per_round": int(send_bytes),
-            "predicted_ms_per_round": round(
+            "predicted_ms_per_round": None if ici_gbs is None else round(
                 wire_bytes / (ici_gbs * 1e9) * 1e3, 6),
             "interfaces": traffic,
         },

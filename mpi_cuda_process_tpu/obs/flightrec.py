@@ -1,8 +1,8 @@
 """Post-mortem flight recorder: a bounded event ring + one-file bundle.
 
 Telemetry logs answer "what happened" only while the telemetry dir
-survives; a wedged real-chip round leaves its evidence on a machine
-that may be recycled before anyone reads it.  The flight recorder is
+survives; a chip run leaves its evidence on a machine that is thrown
+away after the call.  The flight recorder is
 the black box: every session keeps a bounded in-memory ring of its most
 recent events (a :class:`~.trace.TraceWriter` mirror — zero extra I/O,
 zero ops in the jitted step), and on any terminal verdict
@@ -15,10 +15,6 @@ JSON bundle** is written next to the log:
 * open spans at bundle time (root + the emitting thread's stack)
 * anomaly findings (obs/anomaly.py) and the final verdict
 * the ledger's ``best_known`` row for this label (what "normal" was)
-* a ``diagnose_tunnel`` verdict (opt-in: the probe ladder spawns
-  subprocesses — ``OBS_BUNDLE_TUNNEL=1``, default for the on-demand
-  script, off for in-run emission so a failing run's teardown stays
-  bounded)
 * a whitelisted env snapshot (fault injection, backend selection)
 
 ``scripts/obs_report.py`` renders a bundle exactly like a log, and a
@@ -33,8 +29,6 @@ from __future__ import annotations
 import collections
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Any, Dict, List, Optional
 
@@ -113,41 +107,6 @@ def env_snapshot() -> Dict[str, str]:
             if k.startswith(_ENV_WHITELIST_PREFIXES)}
 
 
-def tunnel_verdict(run: Optional[bool] = None,
-                   timeout_s: float = 90.0) -> Dict[str, Any]:
-    """One ``diagnose_tunnel`` probe-ladder verdict for the bundle.
-
-    ``run=None`` consults ``OBS_BUNDLE_TUNNEL`` (default off: the probe
-    ladder spawns jax subprocesses, too heavy for every aborted run's
-    teardown).  Failure modes collapse to an honest UNAVAILABLE rather
-    than blocking the bundle.
-    """
-    if run is None:
-        run = os.environ.get("OBS_BUNDLE_TUNNEL", "0") not in ("0", "")
-    if not run:
-        return {"verdict": "NOT_RUN",
-                "detail": "probe ladder skipped (OBS_BUNDLE_TUNNEL unset)"}
-    script = os.path.join(_REPO, "scripts", "diagnose_tunnel.py")
-    try:
-        out = subprocess.run(
-            [sys.executable, script, "--timeout",
-             str(max(5.0, timeout_s * 0.4))],
-            capture_output=True, text=True, timeout=timeout_s)
-        for line in out.stdout.splitlines():
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            rec = json.loads(line)
-            return {"verdict": rec.get("verdict", "UNKNOWN"),
-                    "detail": rec.get("detail"),
-                    "probes": rec.get("probes")}
-        return {"verdict": "UNAVAILABLE",
-                "detail": f"no verdict line (rc={out.returncode})"}
-    except Exception as e:  # noqa: BLE001 — never block the bundle
-        return {"verdict": "UNAVAILABLE",
-                "detail": f"{type(e).__name__}: {e}"[:300]}
-
-
 def _best_known_for(manifest: Optional[Dict[str, Any]]) -> Optional[Dict]:
     """The ledger's best_known row for this run's label, best-effort."""
     try:
@@ -178,7 +137,6 @@ def build_bundle(manifest: Optional[Dict[str, Any]],
                  events_seen: Optional[int] = None,
                  open_span_list: Optional[List[Dict[str, Any]]] = None,
                  extra_events: Optional[Dict[str, List[Dict]]] = None,
-                 run_tunnel: Optional[bool] = None,
                  last_n: int = DEFAULT_LAST_N) -> Dict[str, Any]:
     """Assemble a self-contained post-mortem; validates before returning.
 
@@ -215,7 +173,6 @@ def build_bundle(manifest: Optional[Dict[str, Any]],
         "open_spans": open_span_list or [],
         "anomalies": anomalies,
         "best_known": _best_known_for(manifest),
-        "tunnel": tunnel_verdict(run=run_tunnel),
         "env": env_snapshot(),
     }
     if extra_events:
@@ -262,9 +219,6 @@ def validate_bundle(b: Any) -> Dict[str, Any]:
     for key in ("open_spans", "anomalies"):
         if not isinstance(b.get(key), list):
             problems.append(f"{key} must be a list")
-    tun = b.get("tunnel")
-    if not isinstance(tun, dict) or not isinstance(tun.get("verdict"), str):
-        problems.append("tunnel must be a dict with a str verdict")
     if not isinstance(b.get("env"), dict):
         problems.append("env must be a dict")
     if problems:
@@ -313,7 +267,6 @@ def is_bundle_file(path: str) -> bool:
 
 def bundle_from_session(session, reason: str,
                         verdict: Optional[str] = None,
-                        run_tunnel: Optional[bool] = None,
                         extra_events: Optional[Dict[str, List[Dict]]] = None,
                         ) -> Optional[str]:
     """Emit a bundle from a live session's ring; returns the path or None.
@@ -329,19 +282,18 @@ def bundle_from_session(session, reason: str,
             flight.manifest, flight.events(), reason, verdict=verdict,
             events_seen=flight.events_seen,
             open_span_list=open_spans(session),
-            extra_events=extra_events, run_tunnel=run_tunnel)
+            extra_events=extra_events)
         return write_bundle(bundle, default_bundle_path(session.path))
     except Exception:  # noqa: BLE001 — never fail the failing run harder
         return None
 
 
 def bundle_from_log(log_path: str, reason: str = "on-demand",
-                    run_tunnel: Optional[bool] = None,
                     out_path: Optional[str] = None) -> str:
     """On-demand bundle from a finished (or abandoned) telemetry log."""
     manifest, events = trace_lib.read_log(log_path)
     if manifest.get("kind") != "manifest":
         raise ValueError(f"{log_path}: first record is not a manifest")
     bundle = build_bundle(manifest, events, reason,
-                          events_seen=len(events), run_tunnel=run_tunnel)
+                          events_seen=len(events))
     return write_bundle(bundle, out_path or default_bundle_path(log_path))

@@ -35,7 +35,7 @@ import socket
 import subprocess
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 # Schema 2 (round 14) adds multi-host provenance — process_index /
 # process_count / hostname — the fields a future aggregator needs to
@@ -106,26 +106,33 @@ def _builder_rev() -> Optional[int]:
         return None
 
 
-def provenance() -> Dict[str, Any]:
-    """The code+hardware identity block stamped into every manifest."""
+# The device identity of a launcher's manifest: a process that starts
+# chip children never initializes a backend itself (the chip belongs to
+# one process at a time), so it leaves the identity unset — the
+# children's own manifests carry it.
+UNSET = "unset"
+
+
+def provenance(launcher: bool = False) -> Dict[str, Any]:
+    """The code+hardware identity block stamped into every manifest.
+
+    ``launcher=True``: touch no jax backend (device fields ``unset``)."""
     import jax
 
     from .. import __version__
 
-    try:
+    if launcher:
+        backend, device_kind, device_count = UNSET, UNSET, 0
+        process_index, process_count = 0, 1
+    else:
+        backend = jax.default_backend()
         devs = jax.devices()
-        device_kind = devs[0].device_kind
-        device_count = len(devs)
-    except Exception:  # noqa: BLE001 — a wedged backend must not block
-        device_kind, device_count = "unknown", 1
-    try:
+        device_kind, device_count = devs[0].device_kind, len(devs)
         # the multi-host identity (schema 2): which process of how many
         # wrote this manifest — what lets an aggregator merge per-host
         # status pages instead of guessing from filenames
         process_index = int(jax.process_index())
         process_count = int(jax.process_count())
-    except Exception:  # noqa: BLE001 — same wedged-backend discipline
-        process_index, process_count = 0, 1
     try:
         hostname = socket.gethostname() or "unknown"
     except Exception:  # noqa: BLE001
@@ -134,7 +141,7 @@ def provenance() -> Dict[str, Any]:
         "git_sha": _git_sha(),
         "builder_rev": _builder_rev(),
         "jax_version": jax.__version__,
-        "backend": jax.default_backend(),
+        "backend": backend,
         "device_kind": device_kind,
         "device_count": device_count,
         "process_index": process_index,
@@ -144,14 +151,15 @@ def provenance() -> Dict[str, Any]:
     }
 
 
-def build_manifest(tool: str, run: Dict[str, Any],
+def build_manifest(tool: str, run: Dict[str, Any], launcher: bool = False,
                    **extra: Any) -> Dict[str, Any]:
     """Assemble and validate a manifest record.
 
     ``tool`` names the emitting entry point (cli/bench/measure/scaling);
     ``run`` is its config dict (the full RunConfig for the CLI, the
     harness arguments for the benchmark tools).  ``extra`` lands at the
-    top level (e.g. ``mesh_devices``).
+    top level (e.g. ``mesh_devices``).  ``launcher``: see
+    :func:`provenance`.
     """
     m: Dict[str, Any] = {
         "schema": SCHEMA_VERSION,
@@ -159,7 +167,7 @@ def build_manifest(tool: str, run: Dict[str, Any],
         "tool": tool,
         "created_at": time.time(),
         "run": dict(run),
-        "provenance": provenance(),
+        "provenance": provenance(launcher),
     }
     m.update(extra)
     validate_manifest(m)
@@ -207,8 +215,10 @@ def validate_manifest(m: Any) -> Dict[str, Any]:
                 problems.append(
                     f"provenance.{key} must be {typ.__name__} "
                     f"(got {prov.get(key)!r})")
-        if prov.get("device_count", 0) < 1:
-            problems.append("provenance.device_count must be >= 1")
+        if prov.get("device_count", 0) < 1 and \
+                prov.get("backend") != UNSET:
+            problems.append("provenance.device_count must be >= 1 "
+                            "(0 only on a launcher's unset identity)")
         if "process_count" in prov and \
                 isinstance(prov.get("process_count"), int) and \
                 prov["process_count"] < 1:
@@ -407,36 +417,3 @@ class LogTail:
             else:
                 self.malformed += 1
         return out
-
-
-def find_latest_manifest(
-    search: Optional[Sequence[str]] = None,
-) -> Optional[Tuple[str, Dict[str, Any]]]:
-    """Newest valid manifest among ``*.jsonl`` logs in ``search`` dirs.
-
-    Defaults to :func:`default_telemetry_dir`.  Returns ``(path,
-    manifest)`` by ``created_at``, or None when nothing valid exists —
-    the pointer bench.py's wedged-path record embeds so a ``stale:
-    true`` scoreboard names the last run that DID leave evidence.
-    """
-    dirs = list(search) if search else [default_telemetry_dir()]
-    best: Optional[Tuple[str, Dict[str, Any]]] = None
-    for d in dirs:
-        try:
-            names = os.listdir(d)
-        except OSError:
-            continue
-        for name in names:
-            if not name.endswith(".jsonl"):
-                continue
-            path = os.path.join(d, name)
-            try:
-                with open(path) as fh:
-                    first = fh.readline()
-                manifest = validate_manifest(json.loads(first))
-            except Exception:  # noqa: BLE001 — skip foreign/corrupt files
-                continue
-            if best is None or \
-                    manifest["created_at"] > best[1]["created_at"]:
-                best = (path, manifest)
-    return best

@@ -1,19 +1,9 @@
-"""Version-tolerant resolution of the Pallas-TPU symbols this package uses.
+"""The Pallas-TPU symbols this package uses, pinned to the installed JAX.
 
-JAX renamed the TPU compiler-params dataclass across releases:
-``pltpu.TPUCompilerParams`` (<= 0.4.x) became ``pltpu.CompilerParams``
-(newer releases keep the old name as a deprecated alias, until they
-don't).  The installed JAX decides which spelling exists, so hard-coding
-either one turns an environment change into six opaque test-collection
-errors (the round-5 seed failure mode).  Every pallas module resolves the
-class through :func:`compiler_params` instead.
-
-The rest of the ``pltpu`` surface this package touches (``roll``,
-``SMEM``/``ANY`` memory spaces, ``VMEM`` scratch, ``SemaphoreType``,
-``make_async_copy``) has been stable across the supported range; they are
-listed in :data:`REQUIRED_PLTPU_SYMBOLS` so the compat smoke test
-(tests/test_compat.py) fails as ONE named assertion — not as collection
-errors — the day any of them drifts too.
+Every pallas module builds its compiler params through
+:func:`compiler_params`; the ``pltpu`` names the kernels touch are listed
+in :data:`REQUIRED_PLTPU_SYMBOLS` so tests/test_compat.py fails as ONE
+named assertion — not as collection errors — the day any of them drifts.
 """
 
 from __future__ import annotations
@@ -21,8 +11,7 @@ from __future__ import annotations
 from jax.experimental.pallas import tpu as pltpu
 
 # Symbols the pallas modules reference directly off ``pltpu``; audited from
-# the package source (grep ``pltpu\.``).  The compiler-params class is
-# resolved separately below because its NAME is what drifts.
+# the package source (grep ``pltpu\.``).
 REQUIRED_PLTPU_SYMBOLS = (
     "roll",
     "SMEM",
@@ -38,23 +27,12 @@ REQUIRED_PLTPU_SYMBOLS = (
 )
 
 
-def _resolve_compiler_params_cls():
-    for name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, name, None)
-        if cls is not None:
-            return cls
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; installed JAX is outside the supported range")
-
-
-CompilerParams = _resolve_compiler_params_cls()
+CompilerParams = pltpu.CompilerParams
 
 
 def compiler_params(**kwargs):
-    """Construct the TPU compiler-params object under whichever name the
-    installed JAX exports.  Keyword-compatible across the rename
-    (``vmem_limit_bytes``, ``dimension_semantics`` are stable fields)."""
+    """The TPU compiler-params object (``vmem_limit_bytes``,
+    ``dimension_semantics``, ...)."""
     return CompilerParams(**kwargs)
 
 

@@ -85,13 +85,13 @@ _MICRO2D = {
 # bodies hold different working sets (grayscott carries uvv + two
 # Laplacians across two fields; wave's u_prev is tap-free; sor keeps the
 # relaxed copy + color mask).  Measured against the full raised scoped
-# limit so the headline 2048^2 cases (16.8 MiB/grid) pass the gate; a
-# residual compile-time OOM on the real chip surfaces as a recorded error
-# (campaign) or the CLI auto-retry's jnp fallback — the envelope gets
-# re-calibrated from the *_full16/32 campaign labels (round-3 advisor
-# finding: one untuned scalar admitted family-dependent OOM risk).
+# limit.  Life's factor is the v5e compiler's own count: an AOT compile
+# of life 2048^2 k=16 used 153.91 MiB of scoped VMEM (9.2 grid copies;
+# PR 21), so 2048^2 int32 Life is declined; heat2d 2048^2 k=16 compiles
+# at factor 5.  A kernel the compiler still refuses raises its own
+# error — nothing falls back.
 _LIVE_FACTOR = {
-    "life": 5,        # 8-tap neighbor sum: acc + roll temp + new
+    "life": 10,       # 8-tap int neighbor sum (compiler-measured 9.2)
     "heat2d": 5,      # 4-tap Laplacian accumulator
     "mdf": 5,
     "advect2d": 5,    # <=2 upwind taps, but same staging floor

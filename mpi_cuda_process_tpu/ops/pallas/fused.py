@@ -203,8 +203,8 @@ def _micro_advect(stencil, interpret):
 def _micro_grayscott(stencil, interpret):
     # Two coupled diffusing fields, BOTH with footprints (unlike wave's
     # neighbor-free carry) — the jnp path pays 4 HBM arrays per step and
-    # measured 14.4 Gcells/s at 256^3 (results_r03.json); fusing k steps
-    # amortizes all of it.
+    # measured 14.4 Gcells/s at 256^3 (2026-07-29..31 chip campaign);
+    # fusing k steps amortizes all of it.
     du = float(stencil.params["du"])
     dv = float(stencil.params["dv"])
     f = float(stencil.params["f"])
@@ -317,7 +317,7 @@ def _fused_kernel(micro, nfields, k, margin, halo, bz, by, shape, periodic,
         y_off + pl.program_id(1) * by - margin, shape, halo, periodic,
         parity)
     # k<=4 unrolls (measured-fast); deeper k runs as a fori_loop — the
-    # unrolled bf16 k=8 hung the Mosaic compile (results_r03.json
+    # unrolled bf16 k=8 hung the Mosaic compile (2026-07-29..31 chip campaign,
     # heat3d_256_bf16_fused8), and a loop body keeps program size constant.
     fields = _run_micros(micro, fields, frame, extra, k)
     for o, f in zip(outs, fields):
@@ -364,8 +364,8 @@ def _fused_raw_kernel(micro, nfields, k, margin, halo, bz, by, shape,
     granularity ``2*margin``) instead of four blocks of a z/y-padded copy —
     so no full-grid pad transient ever materializes.  At 1024^3 f32 the
     padded path's extra ~4.3 GiB copy was the RESOURCE_EXHAUSTED
-    (results_r03.json heat3d_1024_f32_fused4); pad-free needs only the two
-    state buffers.
+    (2026-07-29..31 campaign label heat3d_1024_f32_fused4); pad-free needs
+    only the two state buffers.
 
     The assembled window carries margin ``2*margin`` per side (overlapping
     BlockSpecs must start block-aligned, and the window origin sits at
@@ -1117,7 +1117,7 @@ def _pick_tiles(Z: int, Y: int, X: int, margin: int, itemsize: int,
         # sublane-tile-aligned FOR THE DTYPE.  f32 needs 2m % 8; bf16 needs
         # 2m % 16 (so k=8 for halo-1 stencils, not k=4 — the round-3 bf16
         # 512^3 "hang"/HTTP-500 was a misaligned-bf16-window Mosaic compile,
-        # results_r03.json heat3d_512_bf16_fused4).
+        # 2026-07-29..31 campaign label heat3d_512_bf16_fused4).
         return None
     # Sub-f32 dtypes: budget as if f32, capping tiles at the f32 picks —
     # the proven envelope.  Revisit the halved-bytes headroom with a tile
@@ -1150,9 +1150,10 @@ def fused_supported(stencil: Stencil) -> bool:
 # padded transient, output) while the pad copy runs; past this many bytes
 # the 9-block pad-free kernel is selected instead (v5e HBM is 16 GiB; the
 # padded path's transient was the 1024^3 f32 RESOURCE_EXHAUSTED,
-# results_r03.json).  Below it the padded kernel stays the default — it is
-# the measured 107 Gcells/s configuration — until the campaign measures
-# pad-free at 256^3/512^3 (labels *_padfree4 in benchmarks/measure.py).
+# 2026-07-29..31 chip campaign).  Below it the padded kernel stays the
+# default — it is the measured 107 Gcells/s configuration — until the
+# campaign measures pad-free at 256^3/512^3 (labels *_padfree4 in
+# benchmarks/measure.py).
 _PADFREE_ABOVE_BYTES = 6 * 1024**3
 
 

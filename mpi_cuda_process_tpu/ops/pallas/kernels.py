@@ -48,13 +48,11 @@ _MAX_2D_VMEM_CELLS = 2 * 1024 * 1024
 # Mosaic's default scoped-vmem limit is 16 MiB — v5e physically has 128 MiB
 # of VMEM, and the z-chunk kernels want big chunks (the (bz+2h)/bz halo
 # re-read overhead shrinks with bz).  Raising the limit was the fix for the
-# round-2 "remote_compile HTTP 500" compile failures: at 256^3 the kernel's
-# true scoped usage (pipeline double-buffers + the in-kernel concatenate +
-# tap intermediates) was 17.3 MiB against the 16 MiB default.
+# round-2 scoped-VMEM compile failures: at 256^3 the kernel's true scoped
+# usage (pipeline double-buffers + the in-kernel concatenate + tap
+# intermediates) was 17.3 MiB against the 16 MiB default.
 _VMEM_LIMIT_BYTES = 100 * 1024 * 1024
-# Constructed through the compat resolver: the class is named
-# CompilerParams or TPUCompilerParams depending on the installed JAX
-# (ops/pallas/compat.py).
+# Constructed through ops/pallas/compat.py.
 _COMPILER_PARAMS = compiler_params(
     vmem_limit_bytes=_VMEM_LIMIT_BYTES,
     dimension_semantics=("arbitrary",),

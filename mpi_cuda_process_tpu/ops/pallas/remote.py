@@ -289,7 +289,7 @@ def build_ring_exchange_call(
 
     kernel = functools.partial(_ring_kernel, nc, axis, csize, nslots,
                                remote)
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
     if remote:
         in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM)] + in_specs
     cp = None
@@ -300,7 +300,7 @@ def build_ring_exchange_call(
     call = pl.pallas_call(
         kernel,
         in_specs=in_specs,
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2,
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_shape=[jax.ShapeDtypeStruct(shape, dtype)] * 2,
         interpret=interpret,
         compiler_params=cp,

@@ -156,19 +156,23 @@ def _stream_body(micro, nfields, k, halo, wm, wm_a, bz, by, bx, lshape,
     yj = pl.program_id(1 if order == "xy" else 0)
     if order == "rev":
         yj = ny - 1 - yj
-    ylo = 0 if one_strip else jnp.clip(yj * by - wm_a, 0, Y - wyc)
+    sub = _sublane(jnp.dtype(ins[0].dtype).itemsize)
+    ylo = 0 if one_strip else pl.multiple_of(
+        jnp.clip(yj * by - wm_a, 0, Y - wyc), sub)  # gated alignment
     if bx is None:
         wx, xlo, x_idx = X, 0, ()
         store_x, out_x = 0, ()
     else:
         wx = bx + 2 * _XSHELL
         xj = pl.program_id(0 if order == "xy" else 1)
-        xlo = jnp.clip(xj * bx - _XSHELL, 0, X - wx)
+        xlo = pl.multiple_of(jnp.clip(xj * bx - _XSHELL, 0, X - wx),
+                             _XSHELL)
         x_idx = (pl.ds(xlo, wx),)
         store_x, out_x = xj * bx - xlo, (pl.ds(xj * bx, bx),)
 
-    def body(scratch, sems, slab_mem=None, slab_sems=None, yring=None,
-             ysems=None, corner_mem=None, corner_sems=None):
+    def body(scratch, sems, obuf, osems, slab_mem=None, slab_sems=None,
+             yring=None, ysems=None, corner_mem=None, corner_sems=None,
+             stage=None):
         def _slot(chunk):
             return jax.lax.rem(chunk, _NSLOTS) if _traced(chunk) \
                 else chunk % _NSLOTS
@@ -341,20 +345,35 @@ def _stream_body(micro, nfields, k, halo, wm, wm_a, bz, by, bx, lshape,
                                          halo, False, parity, x0=xlo)
             fields = _run_micros(micro, fields, frame, extra, k)
             for f in range(nfields):
-                outs[f][(pl.ds(c * bz, bz), pl.ds(yj * by, by))
-                        + out_x] = (
-                    jax.lax.dynamic_slice(
-                        fields[f], (store_z, store_y, store_x),
-                        (bz, by, bx if bx is not None else X)))
+                if not is_lo:
+                    out_dma(f, c - 1).wait()  # obuf[f] is free again
+                obuf[f] = _store_slice(
+                    fields[f], stage, store_z, store_y, store_x,
+                    (bz, by, bx if bx is not None else X))
+                out_dma(f, c).start()
+
+        def out_dma(f, chunk):
+            # outputs live in HBM (ANY): the core leaves VMEM by DMA,
+            # overlapped with the next chunk's compute
+            return pltpu.make_async_copy(
+                obuf.at[f],
+                outs[f].at[(pl.ds(chunk * bz, bz), pl.ds(yj * by, by))
+                           + out_x],
+                osems.at[f])
 
         process(0, True, False)
         jax.lax.fori_loop(
             1, nc - 1, lambda c, _: (process(c, False, False), ())[1], ())
         process(nc - 1, False, True)
+        for f in range(nfields):
+            out_dma(f, nc - 1).wait()
 
     kwargs = dict(
         scratch=pltpu.VMEM((nfields, _NSLOTS * bz, wyc, wx), ins[0].dtype),
         sems=pltpu.SemaphoreType.DMA((nfields, _NSLOTS)),
+        obuf=pltpu.VMEM((nfields, bz, by, X if bx is None else bx),
+                        ins[0].dtype),
+        osems=pltpu.SemaphoreType.DMA((nfields,)),
     )
     if slabs is not None:
         kwargs["slab_mem"] = pltpu.VMEM((nfields, 2, wm, wyc, wx),
@@ -367,7 +386,34 @@ def _stream_body(micro, nfields, k, halo, wm, wm_a, bz, by, bx, lshape,
         kwargs["corner_mem"] = pltpu.VMEM(
             (nfields, 4, wm, wm_a, wx), ins[0].dtype)
         kwargs["corner_sems"] = pltpu.SemaphoreType.DMA((nfields, 4))
+    if _staged(two_axis, bx):
+        kwargs["stage"] = pltpu.VMEM((wz, wy, wx), ins[0].dtype)
     pl.run_scoped(body, **kwargs)
+
+
+def _staged(two_axis, bx) -> bool:
+    """Is a window's store offset traced (the strip id picks it)?  Then
+    the store slice reads back through a VMEM staging window."""
+    return not two_axis or bx is not None
+
+
+def _store_slice(win, stage, z, y, x, sizes):
+    """The ``sizes`` core of a processed window at offset ``(z, y, x)``.
+
+    Static offsets slice the value.  A traced offset reads the window
+    back through the ``stage`` VMEM ref with ``pl.ds``: Mosaic lowers no
+    value-level ``dynamic_slice``.  Pure data movement either way."""
+    bz, by, bx = sizes
+    if not any(_traced(v) for v in (z, y, x)):
+        return win[z:z + bz, y:y + by, x:x + bx]
+    stage[...] = win
+    # traced offsets are aligned by the gates: y a sublane multiple (by,
+    # wm_a, Y are), x a lane multiple (bx and _XSHELL are)
+    if _traced(y):
+        y = pl.multiple_of(y, _sublane(jnp.dtype(win.dtype).itemsize))
+    if _traced(x):
+        x = pl.multiple_of(x, 128)
+    return stage[pl.ds(z, bz), pl.ds(y, by), pl.ds(x, bx)]
 
 
 def _traced(v) -> bool:
@@ -501,9 +547,13 @@ def _strip_live_bytes(bz, by, bx, X, wm, wm_a, budget_item, nfields,
     strip = wyc * _lane_round(wx) * budget_item
     win = wy * _lane_round(wx) * budget_item
     # ring + 3-chunk concat + window with ~3 live micro temporaries +
-    # the store slice
+    # the store slice + the output DMA buffer (+ the staging window a
+    # traced store offset reads back through)
+    obuf = bz * by * _lane_round(X if bx is None else bx) * budget_item
     live = (_NSLOTS * bz * strip + 3 * bz * strip
-            + 4 * wz * win + bz * win) * nfields
+            + 4 * wz * win + bz * win + obuf) * nfields
+    if _staged(two_axis, bx):
+        live += wz * win
     if sharded:
         # the slab ring (both sides, every field) + the edge chunks'
         # splice-concat temporary

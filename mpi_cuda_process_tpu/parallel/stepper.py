@@ -22,27 +22,11 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # newer JAX exposes shard_map at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-# The replication/varying-mesh-axes check kwarg was renamed check_rep ->
-# check_vma across JAX releases; resolve which spelling the installed
-# version takes (the same version-tolerance discipline as
-# ops/pallas/compat.py — API drift must not break step construction).
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma"
-             if "check_vma" in _inspect.signature(_shard_map).parameters
-             else "check_rep")
-
-
 def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` with the check kwarg normalized to ``check_vma``
-    across JAX versions.  Every stepper builds through this."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+    """``jax.shard_map`` with keyword specs.  Every stepper builds through
+    this."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 from ..driver import frame_mask
 from ..ops.stencil import Fields, Stencil

@@ -34,9 +34,9 @@ Sites (where the framework calls :func:`maybe_fire`):
   built/jitted: the host-side stand-in for "the compile hung".
 * ``label``      — at the top of a measurement-campaign label
   (``benchmarks/measure.py``); ``name=LABEL`` targets one label.
-* ``heartbeat``  — the heartbeat's stall probe: action ``wedge`` makes
-  the probe return a WEDGED verdict instead of spawning subprocesses
-  (see :func:`injected_heartbeat_verdict`).
+* ``heartbeat``  — the heartbeat's stall episode: action ``wedge``
+  escalates it to a WEDGED verdict (see
+  :func:`injected_heartbeat_verdict`).
 * ``numerics``   — deterministic state corruption: action ``nan`` (its
   only one) poisons ONE cell of the first inexact field with NaN at the
   first chunk boundary at/past ``step=N`` (``FAULT_INJECT=numerics:
@@ -280,13 +280,12 @@ def injected_numeric_poison(step: Optional[int] = None) -> Optional[FaultSpec]:
 
 
 def injected_heartbeat_verdict() -> Optional[Dict[str, str]]:
-    """The ``heartbeat:wedge`` site: a deterministic WEDGED probe verdict.
+    """The ``heartbeat:wedge`` site: a deterministic WEDGED verdict.
 
-    Consulted by :class:`~..obs.heartbeat.Heartbeat` before running its
-    real (subprocess-spawning) probe; returns None when no wedge fault
-    is active for this attempt.  Not consumed — the injected backend
-    stays wedged for every stall episode of the process, like a real
-    wedge would.
+    Consulted by :class:`~..obs.heartbeat.Heartbeat` once per stall
+    episode; returns None when no wedge fault is active for this
+    attempt.  Not consumed — the injected backend stays wedged for every
+    stall episode of the process.
     """
     for spec in active_specs():
         if spec.site == "heartbeat" and spec.action == "wedge" and \
@@ -294,7 +293,7 @@ def injected_heartbeat_verdict() -> Optional[Dict[str, str]]:
             return {"verdict": "WEDGED",
                     "detail": f"injected fault ({spec.raw}) — "
                               "deterministic stand-in for a wedged "
-                              "backend probe"}
+                              "backend"}
     return None
 
 

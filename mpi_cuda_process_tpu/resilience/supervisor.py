@@ -116,53 +116,14 @@ def latest_checkpoint_step(path: Optional[str]) -> Optional[int]:
         return None
 
 
-def find_latest_checkpoint(
-    search: Optional[Sequence[str]] = None,
-) -> Optional[Tuple[str, int]]:
-    """The resume pointer for a wedged box: ``(checkpoint_dir, step)``.
-
-    Scans the telemetry manifests (newest first, by ``created_at``) for
-    a ``run.checkpoint_dir`` whose directory still holds a loadable
-    checkpoint.  This is what bench.py's wedged-path record embeds next
-    to ``last_real_measurement`` so the ``stale: true`` scoreboard also
-    names where a human (or this supervisor) can resume from.
-    """
-    from ..obs import trace as trace_lib
-
-    dirs = list(search) if search else [trace_lib.default_telemetry_dir()]
-    manifests: List[Tuple[float, Dict[str, Any]]] = []
-    for d in dirs:
-        try:
-            names = os.listdir(d)
-        except OSError:
-            continue
-        for name in names:
-            if not name.endswith(".jsonl"):
-                continue
-            try:
-                with open(os.path.join(d, name)) as fh:
-                    m = trace_lib.validate_manifest(
-                        json.loads(fh.readline()))
-            except Exception:  # noqa: BLE001 — skip foreign/corrupt logs
-                continue
-            manifests.append((m.get("created_at", 0.0), m))
-    for _, m in sorted(manifests, key=lambda t: t[0], reverse=True):
-        ckd = (m.get("run") or {}).get("checkpoint_dir")
-        step = latest_checkpoint_step(ckd)
-        if step is not None:
-            return str(ckd), int(step)
-    return None
-
-
 # --------------------------------------------------------------- child
 
 class ProcHandle:
     """A supervised child: its own process group, SIGKILL-cleanable.
 
     The kill must take the whole group — the child may have spawned
-    probe subprocesses of its own (the heartbeat's bounded probes), and
-    an orphaned grandchild holding the backend open is exactly the
-    two-process wedge hazard the campaign notes warn about.
+    subprocesses of its own, and an orphaned grandchild holding the chip
+    would keep the next attempt off it (one process per chip).
     """
 
     def __init__(self, proc: subprocess.Popen):
@@ -507,6 +468,7 @@ def run_supervised(cfg) -> int:
         session = obs.open_session(
             sibling_path(telemetry_base, "supervisor"), tool="supervisor",
             run=dataclasses.asdict(child_cfg), with_heartbeat=False,
+            launcher=True,
             supervisor={"max_restarts": cfg.max_restarts,
                         "restart_backoff_s": cfg.restart_backoff,
                         "stall_timeout_s": cfg.supervise_stall_s})

@@ -764,11 +764,9 @@ class ServingEngine:
         self.name = name
         self.per_job_telemetry = bool(per_job_telemetry)
         self.admission = AdmissionController(hbm_bytes=hbm_bytes)
-        self.compile_cache = compile_cache
-        if compile_cache:
-            from .. import cli
+        from .. import cli
 
-            cli.enable_compile_cache(compile_cache)
+        self.compile_cache = cli.enable_compile_cache(compile_cache)
         self.telemetry_dir = telemetry_dir or \
             trace_lib.default_telemetry_dir()
         os.makedirs(self.telemetry_dir, exist_ok=True)
@@ -806,7 +804,7 @@ class ServingEngine:
             run={"ladder": list(self.ladder), "cadence": self.cadence,
                  "starvation_rounds": self.starvation_rounds,
                  "shrink_after_rounds": self.shrink_after_rounds,
-                 "compile_cache": compile_cache},
+                 "compile_cache": self.compile_cache},
             with_heartbeat=False, **extra)
 
     # -- telemetry ------------------------------------------------------

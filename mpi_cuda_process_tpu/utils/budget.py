@@ -32,19 +32,23 @@ import jax.numpy as jnp
 # (bz+2m, by+2m, X) VMEM-to-HBM staging copies), and allocator rounding.
 _OVERHEAD_FRAC = 0.10
 
-# v5e HBM when the backend doesn't report a limit.
-_DEFAULT_HBM_BYTES = 16 * 1024**3
+# The CPU test platform reports no memory limit; the estimator's
+# arithmetic still runs there against this nominal size.  A TPU that
+# reports no limit is an error, never this default.
+_NOMINAL_HOST_BYTES = 16 * 1024**3
 
 
 def device_hbm_bytes() -> int:
-    """Per-device HBM capacity: backend-reported when available."""
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:  # noqa: BLE001 — stats are best-effort everywhere
-        pass
-    return _DEFAULT_HBM_BYTES
+    """Per-device memory capacity as the backend reports it."""
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    if dev.platform == "tpu":
+        raise RuntimeError(
+            f"{dev.device_kind} reports no memory limit "
+            "(memory_stats()['bytes_limit']); the HBM budget needs it")
+    return _NOMINAL_HOST_BYTES
 
 
 def _local_shape(grid: Sequence[int], mesh: Sequence[int]) -> Tuple[int, ...]:

@@ -44,6 +44,7 @@ from __future__ import annotations
 from typing import Dict, Sequence, Tuple
 
 import jax
+import jax.extend.core as jex_core
 
 
 def iter_jaxprs(jaxpr):
@@ -53,9 +54,9 @@ def iter_jaxprs(jaxpr):
         for v in eqn.params.values():
             vals = v if isinstance(v, (list, tuple)) else (v,)
             for u in vals:
-                if isinstance(u, jax.core.ClosedJaxpr):
+                if isinstance(u, jex_core.ClosedJaxpr):
                     yield from iter_jaxprs(u.jaxpr)
-                elif isinstance(u, jax.core.Jaxpr):
+                elif isinstance(u, jex_core.Jaxpr):
                     yield from iter_jaxprs(u)
 
 
@@ -108,9 +109,9 @@ def _eqn_contains_remote_dma(eqn) -> bool:
         vals = v if isinstance(v, (list, tuple)) else (v,)
         for u in vals:
             jx = None
-            if isinstance(u, jax.core.ClosedJaxpr):
+            if isinstance(u, jex_core.ClosedJaxpr):
                 jx = u.jaxpr
-            elif isinstance(u, jax.core.Jaxpr):
+            elif isinstance(u, jex_core.Jaxpr):
                 jx = u
             if jx is None:
                 continue
@@ -190,7 +191,7 @@ def _ancestor_eqns(jx, seeds):
         seen.add(id(eqn))
         out.append(eqn)
         for iv in eqn.invars:
-            if isinstance(iv, jax.core.Literal):
+            if isinstance(iv, jex_core.Literal):
                 continue
             p = producer.get(iv)
             if p is not None:
@@ -404,9 +405,9 @@ def _shard_map_body_jaxprs(closed):
             if eqn.primitive.name != "shard_map":
                 continue
             body = eqn.params.get("jaxpr")
-            if isinstance(body, jax.core.ClosedJaxpr):
+            if isinstance(body, jex_core.ClosedJaxpr):
                 yield body.jaxpr
-            elif isinstance(body, jax.core.Jaxpr):
+            elif isinstance(body, jex_core.Jaxpr):
                 yield body
 
 

@@ -1,13 +1,17 @@
 """ctypes bridge to the native host runtime (native/stencilhost.cpp).
 
-Builds ``libstencilhost.so`` with g++ on first use (cached in
-``native/build/``) and degrades gracefully: every entry point has a pure
-NumPy fallback, so the framework works on machines without a toolchain.
+Builds the library with g++ on first use, from the committed source only:
+the binary is named by a hash of ``native/stencilhost.cpp``
+(``native/build/`` is git-ignored), so a checkout never loads a library
+built from other source.  Degrades gracefully: every entry point has a
+pure NumPy fallback, so the framework works on machines without a
+toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +22,6 @@ import numpy as np
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "stencilhost.cpp")
 _BUILD_DIR = os.path.join(_REPO, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "libstencilhost.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -33,12 +36,21 @@ _DESCR = {
 }
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    """The library built from the current source: named by its hash."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libstencilhost-{digest}.so")
+
+
+def _build(so: str) -> bool:
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{so}.{os.getpid()}.tmp"  # concurrent builders never collide
     cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-pthread",
-           _SRC, "-o", _SO]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)
         return True
     except (OSError, subprocess.SubprocessError):
         return False
@@ -51,14 +63,14 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_SO)
-        ):
-            if not _build():
-                return None
         try:
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+        except OSError:
+            return None
+        if not os.path.exists(so) and not _build(so):
+            return None
+        try:
+            lib = ctypes.CDLL(so)
         except OSError:
             return None
         lib.stencilhost_async_write_npy.restype = ctypes.c_int

@@ -1,20 +1,15 @@
 #!/usr/bin/env python3
 """On-demand flight-recorder bundle from a telemetry log.
 
-    python scripts/obs_bundle.py PATH.jsonl [-o OUT] [--no-tunnel]
+    python scripts/obs_bundle.py PATH.jsonl [-o OUT]
 
 Reads one telemetry JSONL log and writes the self-contained post-mortem
 bundle (``obs/flightrec.py``) next to it — manifest, last-N events,
 anomaly findings, replayed verdict, ledger ``best_known`` for the
-label, ``diagnose_tunnel`` verdict, env snapshot.  The bundle is what
-you hand to a fresh session (or attach to a round report) when the
-telemetry dir itself won't survive: ``scripts/obs_report.py BUNDLE``
-renders it, ``--check`` validates it.
-
-The probe ladder (``diagnose_tunnel``) runs by default here — an
-on-demand post-mortem is exactly when you want the tunnel verdict —
-and is skippable with ``--no-tunnel`` (or ``OBS_BUNDLE_TUNNEL=0`` for
-the in-run emission paths, where it defaults off).
+label, env snapshot.  The bundle is what you hand to a fresh session
+(or attach to a round report) when the telemetry dir itself won't
+survive: ``scripts/obs_report.py BUNDLE`` renders it, ``--check``
+validates it.
 """
 
 import argparse
@@ -34,24 +29,19 @@ def main(argv=None) -> int:
     p.add_argument("path", help="telemetry JSONL log")
     p.add_argument("-o", "--out", default=None,
                    help="bundle path (default: <log>.bundle.json)")
-    p.add_argument("--no-tunnel", action="store_true",
-                   help="skip the diagnose_tunnel probe ladder")
     p.add_argument("--reason", default="on-demand",
                    help="reason recorded in the bundle")
     a = p.parse_args(argv)
     try:
         out = flightrec.bundle_from_log(
-            a.path, reason=a.reason,
-            run_tunnel=False if a.no_tunnel else True,
-            out_path=a.out)
+            a.path, reason=a.reason, out_path=a.out)
     except (OSError, ValueError) as e:
         print(f"obs_bundle: {e}", file=sys.stderr)
         return 2
     b = flightrec.read_bundle(out)
     print(f"wrote {out}")
     print(f"  verdict={b['verdict']} events={len(b['events'])} "
-          f"anomalies={len(b['anomalies'])} "
-          f"tunnel={b['tunnel']['verdict']}")
+          f"anomalies={len(b['anomalies'])}")
     return 0
 
 

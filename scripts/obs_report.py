@@ -18,9 +18,7 @@ manifest schema) and prints:
 
 ``--check`` validates the log against the shared schema and exits
 nonzero on any invalid record — the mode ``scripts/tier1.sh`` runs, so
-a tool drifting off-schema fails the gate.  A pallas-retry sibling
-(``PATH.retry.jsonl``, written by cli.run's auto-retry) is validated
-against the same schema when present.
+a tool drifting off-schema fails the gate.
 
 A ``tool="supervisor"`` log has no chunk events (its children's logs
 carry those), so instead of an empty attribution table it renders the
@@ -38,7 +36,7 @@ render as a findings table — their presence means verdict DEGRADED.
 When PATH is a flight-recorder bundle (``*.bundle.json``, written by
 obs/flightrec.py on a terminal verdict or by ``scripts/obs_bundle.py``)
 it is rendered as a self-contained post-mortem — manifest, event ring,
-anomaly findings, open spans, ledger baseline, tunnel verdict — with
+anomaly findings, open spans, ledger baseline — with
 no need for the original telemetry dir.  ``--check`` on a bundle runs
 the bundle's own self-validation instead of the log schema walk.
 
@@ -106,10 +104,11 @@ def _manifest_block(m) -> str:
 
 
 def _attribution_block(cost, summary) -> str:
-    roof = cost.get("roofline", {})
+    # no roofline on a device the peaks table does not know: "-"
+    roof = cost.get("roofline") or {}
     comm = cost.get("comm")
     t_hbm = roof.get("predicted_ms_per_step_hbm")
-    t_ici = roof.get("predicted_ms_per_step_exchange", 0.0)
+    t_ici = roof.get("predicted_ms_per_step_exchange")
     measured = None
     if summary:
         steady = (summary.get("runtime") or {}).get("steady") or {}
@@ -130,7 +129,7 @@ def _attribution_block(cost, summary) -> str:
                     f"ppermute/pass, width {comm.get('width_m')})")
         rows.append([
             what,
-            f"{t_ici:.4f}",
+            f"{t_ici:.4f}" if t_ici is not None else "-",
             _fmt_bytes(int(comm["ici_bytes_per_step"])) + "/step",
             "(not separable)"])
         # boundary shells: cells within 2m of a sharded wall, re-read/
@@ -151,12 +150,13 @@ def _attribution_block(cost, summary) -> str:
     total_over = roof.get("predicted_mcells_per_s_overlapped")
     total_serial = roof.get("predicted_mcells_per_s_serial")
     rows.append(["TOTAL overlapped (exchange hidden)",
-                 f"{max(t_hbm or 0, t_ici or 0):.4f}",
+                 f"{max(t_hbm or 0, t_ici or 0):.4f}" if roof else "-",
                  f"{total_over} Mcells/s",
                  f"{measured:.4f}" if measured is not None else "-"])
     if comm:
         rows.append(["TOTAL serial (exchange on critical path)",
-                     f"{(t_hbm or 0) + (t_ici or 0):.4f}",
+                     f"{(t_hbm or 0) + (t_ici or 0):.4f}" if roof
+                     else "-",
                      f"{total_serial} Mcells/s", ""])
     return "attribution (predicted vs measured)\n" + _table(
         rows, ["phase", "pred ms/step", "volume", "measured ms/step"])
@@ -464,10 +464,6 @@ def render_bundle(bundle) -> str:
         out.append("ledger best_known for this label: "
                    f"{best.get('value')} {best.get('unit')} "
                    f"(source {best.get('source')})")
-    tunnel = bundle.get("tunnel") or {}
-    out.append(f"tunnel: {tunnel.get('verdict', '?')}"
-               + (f" — {tunnel.get('detail')}" if tunnel.get("detail")
-                  else ""))
     env = bundle.get("env") or {}
     if env:
         out.append("env: " + "  ".join(f"{k}={v}" for k, v in
@@ -575,22 +571,13 @@ def main(argv=None) -> int:
         print(render_bundle(bundle))
         return 0
     if a.check:
-        # the pallas auto-retry writes its own log at PATH.retry.jsonl
-        # (cli.run); when present it must pass the same schema — a
-        # sibling drifting off-schema is the same gate failure
-        to_check = [a.log]
-        retry = a.log + ".retry.jsonl"
-        if os.path.exists(retry):
-            to_check.append(retry)
-        for path in to_check:
-            try:
-                manifest, events = obs_trace.validate_log(path)
-            except (ValueError, OSError) as e:
-                print(f"obs_report --check: INVALID: {e}", file=sys.stderr)
-                return 1
-            print(f"obs_report --check: ok (tool={manifest['tool']}, "
-                  f"schema={manifest['schema']}, {len(events)} events"
-                  + (", retry sibling" if path != a.log else "") + ")")
+        try:
+            manifest, events = obs_trace.validate_log(a.log)
+        except (ValueError, OSError) as e:
+            print(f"obs_report --check: INVALID: {e}", file=sys.stderr)
+            return 1
+        print(f"obs_report --check: ok (tool={manifest['tool']}, "
+              f"schema={manifest['schema']}, {len(events)} events)")
     print(render(a.log))
     return 0
 

@@ -811,18 +811,14 @@ if [ -n "$bundle_path" ] && [ -f "$bundle_path" ]; then
 else
   echo 'give-up flight bundle missing' >&2; rc=1
 fi
-# The committed campaign ledger must render in both one-command
-# summary surfaces: obs_report --ledger (best_known + quarantine
-# table) and the terminal monitor's ledger mode.
-timeout -k 10 120 python scripts/obs_report.py --ledger > /dev/null || rc=1
-timeout -k 10 120 python scripts/obs_top.py benchmarks/ledger.jsonl \
-  --once > /dev/null || rc=1
-# Ledger + perf-gate smoke against a throwaway ledger: backfill the
-# historical BENCH_r0*/results_r0* files (quarantine rules exercised on
-# the real wedge rounds), ingest the smoke manifest, and run the gate in
+# Ledger + perf-gate smoke against a throwaway ledger: ingest the smoke
+# manifest, render it in both one-command summary surfaces (obs_report
+# --ledger and the terminal monitor's ledger mode), and run the gate in
 # --dry mode — the full measurement->ledger->gate loop every build.
-timeout -k 10 120 python scripts/perf_gate.py --backfill \
-  --ledger /tmp/_t1_ledger.jsonl > /dev/null || rc=1
 timeout -k 10 120 python scripts/perf_gate.py /tmp/_t1_obs.jsonl --dry \
   --update-ledger --ledger /tmp/_t1_ledger.jsonl || rc=1
+OBS_LEDGER_PATH=/tmp/_t1_ledger.jsonl timeout -k 10 120 \
+  python scripts/obs_report.py --ledger > /dev/null || rc=1
+timeout -k 10 120 python scripts/obs_top.py /tmp/_t1_ledger.jsonl \
+  --once > /dev/null || rc=1
 exit $rc

@@ -35,6 +35,23 @@ def life_step(grid: np.ndarray) -> np.ndarray:
     return new
 
 
+def life_steps_vec(grid: np.ndarray, n: int) -> np.ndarray:
+    """``n`` generations of :func:`life_step`, vectorized with shifted
+    slices (same B3/S23 rule, same pinned frame) for grids where the
+    per-cell loop is too slow (chip_smoke.py's 4096^2 check)."""
+    grid = np.asarray(grid)
+    for _ in range(n):
+        c = grid[1:-1, 1:-1]
+        nb = sum(grid[1 + dy:grid.shape[0] - 1 + dy,
+                      1 + dx:grid.shape[1] - 1 + dx].astype(np.int32)
+                 for dy in (-1, 0, 1) for dx in (-1, 0, 1) if dy or dx)
+        new = grid.copy()
+        new[1:-1, 1:-1] = ((nb == 3) | ((nb == 2) & (c == 1))).astype(
+            grid.dtype)
+        grid = new
+    return grid
+
+
 def heat_step(grid: np.ndarray, alpha: float) -> np.ndarray:
     """FTCS axis-neighbor diffusion, any ndim; frame pinned."""
     nd = grid.ndim

@@ -35,7 +35,9 @@ def test_compiler_params_resolves():
         CompilerParams, compiler_params,
     )
 
-    assert CompilerParams is not None
+    from jax.experimental.pallas import tpu as pltpu
+
+    assert CompilerParams is pltpu.CompilerParams  # the installed name
     p = compiler_params(vmem_limit_bytes=1 << 20,
                         dimension_semantics=("arbitrary",))
     assert p.vmem_limit_bytes == 1 << 20
@@ -52,10 +54,15 @@ def test_required_pltpu_symbols_present():
 
 
 def test_shard_map_resolves():
-    # stepper.py's try/except import chain must land on a callable
+    # the installed spelling: jax.shard_map with the check_vma kwarg
+    import inspect
+
+    import jax
+
     from mpi_cuda_process_tpu.parallel.stepper import shard_map
 
     assert callable(shard_map)
+    assert "check_vma" in inspect.signature(jax.shard_map).parameters
 
 
 def test_pallas_blockspec_memory_space_kwarg():

@@ -15,10 +15,7 @@ from jax.sharding import PartitionSpec as P
 from mpi_cuda_process_tpu.parallel.halo import exchange_and_pad
 from mpi_cuda_process_tpu.parallel.mesh import make_mesh
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 
 @pytest.mark.parametrize("n_shards,halo", [(2, 1), (4, 1), (2, 2), (4, 3)])

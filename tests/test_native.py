@@ -147,3 +147,15 @@ def test_heat3d27_differential_native_vs_jax():
         cpp_out = native.heat3d27_step_native(cpp_out, 0.15)
     np.testing.assert_allclose(
         np.asarray(jax_out[0]), cpp_out, rtol=1e-5, atol=1e-3)
+
+
+def test_library_is_built_from_the_committed_source():
+    """The loaded binary is named by a hash of native/stencilhost.cpp, so
+    a checkout never loads a library built from other source."""
+    import hashlib
+
+    with open(native._SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    assert native._so_path().endswith(f"libstencilhost-{digest}.so")
+    if native.available():
+        assert os.path.exists(native._so_path())

@@ -12,8 +12,8 @@ Pins the subsystem's four contracts:
   TRACED sharded step actually issues (jaxpr cross-check on virtual
   devices) and, for config 5 on both mesh families, equal to
   utils/budget.py's byte-pinned slab accounting to the byte.
-* **heartbeat** — an injected hang yields STALLED, an injected wedged
-  probe escalates to WEDGED, resumed progress yields RECOVERED.
+* **heartbeat** — an injected hang yields STALLED, the injected wedge fault
+  escalates to WEDGED, resumed progress yields RECOVERED.
 """
 
 import importlib.util
@@ -21,7 +21,6 @@ import json
 import math
 import os
 import sys
-import threading
 import time
 
 import jax
@@ -58,10 +57,6 @@ def test_manifest_roundtrip_and_latest_lookup(tmp_path, monkeypatch):
     assert prov["backend"] == jax.default_backend()
     assert prov["device_count"] == len(jax.devices())
     assert isinstance(prov["jax_version"], str)
-    # the wedged-path pointer finds this log as the newest manifest
-    found = trace.find_latest_manifest()
-    assert found is not None and found[0] == path
-    assert found[1]["tool"] == "cli"
 
 
 def test_validator_rejects_and_names_every_problem(tmp_path):
@@ -229,32 +224,19 @@ def test_measure_emits_same_schema(tmp_path, monkeypatch):
     assert non_span[-1]["labels_run"] == 1
 
 
-def test_bench_telemetry_and_wedge_context(tmp_path, monkeypatch):
-    """Satellite: the wedged-path record embeds the heartbeat verdict
-    and the newest manifest path — ``stale: true`` says WHY in one
-    file."""
+def test_bench_telemetry_manifest(tmp_path, monkeypatch):
+    """The round-gate bench is itself provenance-stamped evidence: its
+    headline record lands as the one result event of a bench manifest."""
     monkeypatch.setenv("OBS_TELEMETRY_DIR", str(tmp_path))
     import bench
 
-    # the healthy path drops a manifest under the telemetry dir
     rec = {"metric": "m", "value": 1.0}
     tel = bench._write_bench_telemetry(rec, (16, 16, 16), 2, 0, "cpu")
     assert tel is not None
     manifest, events = trace.validate_log(tel)
     assert manifest["tool"] == "bench"
     assert events[0]["kind"] == "result" and events[0]["value"] == 1.0
-
-    # the wedged path probes (stubbed) and points at that manifest
-    monkeypatch.setenv("BENCH_OBS_PROBE", "1")
-    monkeypatch.setattr(
-        heartbeat, "probe_verdict",
-        lambda timeout_s=0: {"verdict": "WEDGED", "detail": "injected"})
-    monkeypatch.setattr(bench, "_CACHE", str(tmp_path / "absent.json"))
-    stale = bench._stale_fallback_record()
-    assert stale["stale"] is True
-    assert stale["heartbeat"]["verdict"] == "WEDGED"
-    assert stale["telemetry_manifest"] == tel
-    json.dumps(stale)  # driver-visible record stays one JSON line
+    json.dumps(rec)  # driver-visible record stays one JSON line
 
 
 # ------------------------------------------------------------- runtime
@@ -391,24 +373,18 @@ def _wait_for(pred, timeout=5.0):
     return False
 
 
-def test_heartbeat_stall_escalation_and_recovery():
-    """Injected hang -> STALLED -> (wedged probe) -> WEDGED; progress
+def test_heartbeat_stall_escalation_and_recovery(monkeypatch):
+    """Injected hang -> STALLED -> (injected wedge) -> WEDGED; progress
     resumes -> RECOVERED; one verdict per episode, no event spam."""
+    monkeypatch.setenv("FAULT_INJECT", "heartbeat:wedge")
     progress = [time.monotonic()]
     tr = _ListTrace()
-    probed = threading.Event()
-
-    def probe():
-        probed.set()
-        return {"verdict": "WEDGED", "detail": "injected wedge"}
-
     hb = heartbeat.Heartbeat(lambda: progress[0], trace=tr,
-                             stall_after_s=0.15, poll_s=0.03, probe=probe)
+                             stall_after_s=0.15, poll_s=0.03)
     hb.start()
     try:
         assert _wait_for(lambda: any(
             e["verdict"] == "WEDGED" for e in tr.events))
-        assert probed.is_set()
         verdicts = [e["verdict"] for e in tr.events]
         assert verdicts[0] == "STALLED"  # stall first, then escalation
         assert hb.last_verdict["verdict"] == "WEDGED"
@@ -422,27 +398,22 @@ def test_heartbeat_stall_escalation_and_recovery():
         hb.stop()
 
 
-def test_heartbeat_healthy_backend_keeps_stalled_verdict():
+def test_heartbeat_stall_without_wedge_stays_stalled(monkeypatch):
+    """No subprocess probe: a stall is reported in-process as STALLED and
+    is never escalated without the fault seam."""
+    monkeypatch.delenv("FAULT_INJECT", raising=False)
     progress = [time.monotonic() - 100.0]  # born stalled
     tr = _ListTrace()
     hb = heartbeat.Heartbeat(
-        lambda: progress[0], trace=tr, stall_after_s=0.1, poll_s=0.03,
-        probe=lambda: {"verdict": "NO_TPU", "detail": "cpu box"})
+        lambda: progress[0], trace=tr, stall_after_s=0.1, poll_s=0.03)
     hb.start()
     try:
-        assert _wait_for(lambda: any(
-            "NO_TPU" in str(e.get("detail")) for e in tr.events))
+        assert _wait_for(lambda: bool(tr.events))
+        time.sleep(0.1)
+        assert [e["verdict"] for e in tr.events] == ["STALLED"]
         assert hb.last_verdict["verdict"] == "STALLED"  # not WEDGED
     finally:
         hb.stop()
-
-
-@pytest.mark.slow
-def test_probe_verdict_real_subprocesses():
-    """The real (unstubbed) probe on this box: CPU backend answers, so
-    the verdict must be NO_TPU — bounded, never raising."""
-    v = heartbeat.probe_verdict(timeout_s=120.0)
-    assert v["verdict"] == "NO_TPU", v
 
 
 # ------------------------------------------------------------ costmodel
@@ -542,7 +513,12 @@ def test_step_flops_counter_pinned():
     assert sc["flops_per_step_per_device"] == \
         costmodel.step_flops(h3, (8, 16, 128))
     assert sc["hbm_bytes_per_step_per_device"] == 2 * 8 * 16 * 128 * 4
-    assert sc["roofline"]["predicted_mcells_per_s_overlapped"] > 0
+    # no roofline for a device the peaks table does not know (the CPU)
+    assert sc["roofline"] is None
+    v5e = costmodel.DEVICE_PEAKS["TPU v5 lite"]
+    sc_v5e = costmodel.static_cost(h3, (16, 16, 128), mesh=(2, 1, 1),
+                                   **v5e)
+    assert sc_v5e["roofline"]["predicted_mcells_per_s_overlapped"] > 0
     assert sc["comm"]["ppermute_rounds_per_pass"] == 2
 
 
@@ -628,25 +604,3 @@ def test_obs_report_renders_supervisor_trail(tmp_path, capsys):
                 restarts=0)
     assert report.main([path2]) == 0
     assert "GIVE UP" in capsys.readouterr().out
-
-
-def test_obs_report_check_validates_retry_sibling(tmp_path, capsys):
-    """Satellite: the pallas-retry sibling log (PATH.retry.jsonl,
-    written by cli.run's auto-retry) is validated by --check when
-    present — on the same schema, with the same nonzero-exit rule."""
-    report = _load_script("obs_report_retry_t", "scripts/obs_report.py")
-    main_log = tmp_path / "run.jsonl"
-    with trace.TraceWriter(str(main_log)) as w:
-        w.write_manifest(trace.build_manifest("cli", {"x": 1}))
-        w.event("error", error="Mosaic exploded")
-    retry = tmp_path / "run.jsonl.retry.jsonl"
-    with trace.TraceWriter(str(retry)) as w:
-        w.write_manifest(trace.build_manifest("cli", {"x": 1}))
-        w.event("summary", mcells_per_s=1.0)
-    assert report.main([str(main_log), "--check"]) == 0
-    out = capsys.readouterr().out
-    assert "retry sibling" in out
-
-    # an off-schema sibling fails the gate even when the main log is ok
-    retry.write_text('{"kind": "manifest"}\n')
-    assert report.main([str(main_log), "--check"]) == 1

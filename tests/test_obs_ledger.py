@@ -6,14 +6,12 @@ Synthetic manifests only — no TPU required.  Pins:
   OR note-marked), noise-floor suspects, errored labels, backend
   mismatches, and WEDGED/STALLED heartbeats all land quarantined with
   a reason, and :func:`best_known` can never surface one as a baseline;
-* **backfill idempotence** — the one-shot historical ingest of the
-  repo's real BENCH_r0*/results_r0* files appends once and never again;
+* **backfill idempotence** — the one-shot historical ingest of
+  BENCH_r0*/results_r0* files (small synthetic copies of the historical
+  shapes) appends once and never again;
 * **gate verdicts** — IMPROVED/OK/REGRESSED/NO_BASELINE/QUARANTINED
   against a backfilled ledger, nonzero exit on an injected synthetic
   regression, ``--dry`` always 0 (the acceptance criteria);
-* **wedged-path routing** — bench.py's stale fallback record enters the
-  ledger quarantined (satellite), carrying its heartbeat verdict and
-  the ``last_real_measurement`` pointer.
 """
 
 import importlib.util
@@ -26,7 +24,6 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from mpi_cuda_process_tpu.obs import heartbeat  # noqa: E402
 from mpi_cuda_process_tpu.obs import ledger, trace  # noqa: E402
 
 
@@ -131,14 +128,64 @@ def test_append_rows_idempotent_and_validating(tmp_path):
 
 # ------------------------------------------------------------- backfill
 
-def test_backfill_is_idempotent_and_quarantines_wedged_rounds(tmp_path):
-    """The repo's REAL historical files: BENCH_r04/r05 (0.0 stale) and
-    every suspect/errored campaign label land quarantined; round-3
-    measurements land ok; a second backfill appends nothing."""
+_HEADLINE = "heat3d_7pt_256cubed_single_chip_throughput"
+
+
+@pytest.fixture
+def history_repo(tmp_path):
+    """A checkout-shaped directory holding small synthetic copies of the
+    historical record shapes: a note-marked cached replay (r01), a fresh
+    measurement (r02), a stale-flagged replay (r03), two 0.0 unmeasured
+    rounds (r04/r05), and a campaign table with ok/suspect/errored rows."""
+    repo = tmp_path / "repo"
+    (repo / "benchmarks").mkdir(parents=True)
+    parsed = {
+        1: {"metric": _HEADLINE, "value": 88.0, "unit": "Mcells/s",
+            "backend": "tpu",
+            "note": "cached tpu-backend result: backend unresponsive "
+                    "this run"},
+        2: {"metric": _HEADLINE, "value": 85.0, "unit": "Mcells/s"},
+        3: {"metric": _HEADLINE + "_cached", "value": 106.0,
+            "unit": "Mcells/s", "stale": True, "note": "STALE: replayed"},
+        4: {"metric": "stencil_throughput_unmeasured", "value": 0.0,
+            "unit": "Mcells/s", "stale": True, "note": "unmeasured"},
+        5: {"metric": "stencil_throughput_unmeasured", "value": 0.0,
+            "unit": "Mcells/s", "stale": True, "note": "unmeasured"},
+    }
+    for n, rec in parsed.items():
+        (repo / f"BENCH_r0{n}.json").write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": 0, "tail": "",
+             "parsed": rec}))
+    table = {
+        "heat3d_256_f32_fused4": {
+            "backend": "tpu", "compute": "fused4", "dtype": "float32",
+            "grid": [256, 256, 256], "mcells_per_s": 107.0,
+            "measured_at": 1785386000.5, "ms_per_step": 0.157,
+            "stencil": "heat3d"},
+        "heat3d_256_f32_jnp": {
+            "backend": "tpu", "compute": "jnp", "dtype": "float32",
+            "grid": [256, 256, 256], "mcells_per_s": 86.0,
+            "measured_at": 1785386100.5, "ms_per_step": 0.195,
+            "stencil": "heat3d", "suspect": True},
+        "heat3d_1024_bf16": {
+            "backend": "tpu", "compute": "jnp", "dtype": "bfloat16",
+            "error": "JaxRuntimeError: RESOURCE_EXHAUSTED",
+            "grid": [1024, 1024, 1024], "measured_at": 1785386200.5,
+            "stencil": "heat3d"},
+    }
+    (repo / "benchmarks" / "results_r03.json").write_text(json.dumps(table))
+    return str(repo)
+
+
+def test_backfill_is_idempotent_and_quarantines_wedged_rounds(
+        tmp_path, history_repo):
+    """BENCH_r04/r05 (0.0 stale) and every suspect/errored campaign label
+    land quarantined; fresh measurements land ok; a second backfill
+    appends nothing."""
     path = str(tmp_path / "ledger.jsonl")
-    out = ledger.backfill(repo=REPO, ledger_path=path)
+    out = ledger.backfill(repo=history_repo, ledger_path=path)
     assert out["appended"] == out["found"] > 0
-    again = ledger.backfill(repo=REPO, ledger_path=path)
+    again = ledger.backfill(repo=history_repo, ledger_path=path)
     assert again["appended"] == 0  # idempotent
 
     rows = ledger.read_rows(path)
@@ -290,8 +337,10 @@ def test_restart_trail_rides_rows_and_flags_the_gate(tmp_path, gate_mod,
         "lab_retry")[1].split("\n")[0]
 
 
-def test_gate_backfill_mode(tmp_path, gate_mod, capsys, monkeypatch):
+def test_gate_backfill_mode(tmp_path, gate_mod, capsys, monkeypatch,
+                           history_repo):
     monkeypatch.setenv("OBS_LEDGER_PATH", str(tmp_path / "l.jsonl"))
+    monkeypatch.setattr(ledger, "_REPO", history_repo)
     assert gate_mod.main(["--backfill"]) == 0
     assert "appended" in capsys.readouterr().out
     assert ledger.read_rows(str(tmp_path / "l.jsonl"))
@@ -357,7 +406,7 @@ def test_wedged_log_heartbeat_quarantines_its_rows(tmp_path):
     with trace.TraceWriter(log) as w:
         w.write_manifest(trace.build_manifest(
             "cli", {"stencil": "heat3d", "grid": [64, 64, 64]}))
-        w.event("heartbeat", verdict="WEDGED", detail="tunnel dead")
+        w.event("heartbeat", verdict="WEDGED", detail="no progress")
         w.event("summary", mcells_per_s=42.0)
     ledger.ingest_log(log, lpath)
     row = ledger.read_rows(lpath)[0]
@@ -365,41 +414,6 @@ def test_wedged_log_heartbeat_quarantines_its_rows(tmp_path):
     assert "WEDGED" in row["quarantine"]
     assert row["heartbeat"] == "WEDGED"
 
-
-# -------------------------------------------------- bench wedged routing
-
-def test_bench_wedged_path_routes_quarantined_row(tmp_path, monkeypatch):
-    """Satellite: the stale fallback record lands in the ledger
-    quarantined, with heartbeat verdict + last_real_measurement
-    provenance — and can never be a baseline."""
-    lpath = str(tmp_path / "ledger.jsonl")
-    monkeypatch.setenv("OBS_LEDGER_PATH", lpath)
-    monkeypatch.setenv("OBS_TELEMETRY_DIR", str(tmp_path))
-    monkeypatch.setenv("BENCH_OBS_PROBE", "1")
-    import bench
-
-    monkeypatch.setattr(
-        heartbeat, "probe_verdict",
-        lambda timeout_s=0: {"verdict": "WEDGED", "detail": "injected"})
-    monkeypatch.setattr(bench, "_CACHE", str(tmp_path / "absent.json"))
-    stale = bench._stale_fallback_record()
-    assert stale["stale"] is True
-
-    rows = ledger.read_rows(lpath)
-    assert rows, "wedged path must write a ledger row"
-    assert all(r["status"] == "quarantined" for r in rows)
-    r = rows[0]
-    assert r["heartbeat"] == "WEDGED"
-    assert (r["detail"] or {}).get("last_real_measurement")
-    assert ledger.best_known(rows) == {}  # never a baseline
-
-    # idempotent on a double-fire (watchdog + main race)
-    n_before = len(rows)
-    bench._stale_fallback_record()
-    assert len(ledger.read_rows(lpath)) == n_before
-
-
-# ------------------------------------------------- obs_report --ledger
 
 def test_obs_report_ledger_summary_mode(tmp_path, capsys):
     """Satellite: `obs_report.py --ledger PATH` prints the best_known

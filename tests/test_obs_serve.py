@@ -494,9 +494,19 @@ def test_obs_top_renders_live_url_and_campaign_deltas(
     assert "heat2d_tiny" in out and "+25.0%" in out
 
 
-def test_obs_top_renders_committed_ledger(capsys, obs_top):
-    """Acceptance: the committed campaign ledger renders without error."""
-    path = os.path.join(REPO, "benchmarks", "ledger.jsonl")
+def test_obs_top_renders_a_campaign_ledger(tmp_path, capsys, obs_top):
+    """Acceptance: a campaign ledger (baselines + quarantined rows)
+    renders without error."""
+    from mpi_cuda_process_tpu.obs import ledger as ledger_lib
+
+    path = str(tmp_path / "ledger.jsonl")
+    ledger_lib.append_rows([
+        ledger_lib.make_row("heat3d_256_f32", 100.0, source="s",
+                            backend="tpu", expected_backend="tpu",
+                            measured_at=1.0),
+        ledger_lib.make_row("heat3d_512_f32", 0.0, source="s",
+                            backend="tpu", expected_backend="tpu",
+                            measured_at=2.0)], path)
     assert obs_top.main([path, "--once"]) == 0
     out = capsys.readouterr().out
     assert "baselines" in out and "quarantine reasons" in out

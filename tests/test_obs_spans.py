@@ -535,7 +535,12 @@ def test_obs_top_once_is_a_health_probe(tmp_path, capsys, obs_top):
     assert obs_top.main([gave_up, "--once"]) == 1
     capsys.readouterr()
     # ledger sources have no run health: always 0 (the CI ledger leg)
-    path = os.path.join(REPO, "benchmarks", "ledger.jsonl")
+    from mpi_cuda_process_tpu.obs import ledger as ledger_lib
+
+    path = str(tmp_path / "ledger.jsonl")
+    ledger_lib.append_rows([ledger_lib.make_row(
+        "heat3d_256_f32", 100.0, source="s", backend="tpu",
+        expected_backend="tpu", measured_at=1.0)], path)
     assert obs_top.main([path, "--once"]) == 0
     capsys.readouterr()
 

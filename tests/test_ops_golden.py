@@ -32,6 +32,16 @@ def test_life_matches_golden():
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
+def test_life_vectorized_golden_matches_loop_golden():
+    # chip_smoke.py's 4096^2 Life check rests on the vectorized golden;
+    # frame cells alive on purpose (both must leave them pinned)
+    g = _rng(5).integers(0, 2, size=(13, 19)).astype(np.int32)
+    want = g
+    for _ in range(5):
+        want = golden.life_step(want)
+    np.testing.assert_array_equal(golden.life_steps_vec(g, 5), want)
+
+
 @pytest.mark.parametrize("shape,name,alpha", [
     ((10, 14), "heat2d", 0.25),
     ((6, 7, 9), "heat3d", 1.0 / 6.0),

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 
 from mpi_cuda_process_tpu import (
@@ -173,9 +174,9 @@ def _all_jaxprs(jaxpr):
         for v in eqn.params.values():
             vals = v if isinstance(v, (list, tuple)) else (v,)
             for u in vals:
-                if isinstance(u, jax.core.ClosedJaxpr):
+                if isinstance(u, jex_core.ClosedJaxpr):
                     yield from _all_jaxprs(u.jaxpr)
-                elif isinstance(u, jax.core.Jaxpr):
+                elif isinstance(u, jex_core.Jaxpr):
                     yield from _all_jaxprs(u)
 
 
@@ -207,7 +208,7 @@ def _interior_depends_on_ppermute(step, fields, local_shape):
             if eqn.primitive.name == "ppermute":
                 hit = True
             for iv in eqn.invars:
-                if isinstance(iv, jax.core.Literal):
+                if isinstance(iv, jex_core.Literal):
                     continue
                 p = producer.get(iv)
                 if p is not None:

@@ -239,9 +239,15 @@ def test_copy_calibration_reports_rate(P, tmp_path):
     assert "256^3" in adv2["copy calibration"][1]
 
 
-def test_runs_on_the_live_results_file(P):
-    # the real (seeded) table must parse without raising, whatever its
-    # current mix of successes/errors/timeouts
-    path = os.path.join(_BENCH_DIR, "results_r05.json")
-    rows = list(P.advise(P.load(path)))
+def test_runs_on_a_mixed_results_file(P, tmp_path):
+    # a campaign-shaped table must parse without raising, whatever its
+    # mix of successes/errors/timeouts
+    path = tmp_path / "results.json"
+    path.write_text(json.dumps({
+        "heat3d_256_f32_fused4": _rec(107000.0),
+        "heat3d_256_f32_jnp": _rec(86000.0, suspect=True),
+        "heat3d_1024_bf16": {"error": "RESOURCE_EXHAUSTED"},
+        "copy_512_f32": {"error": "subprocess timeout"},
+    }))
+    rows = list(P.advise(P.load(str(path))))
     assert isinstance(rows, list)

@@ -163,12 +163,12 @@ def test_pick_strip_never_offers_xwindow_past_shell_margin(monkeypatch):
     # the one configuration where the filter is load-bearing: a grown
     # ladder hosts the margin, whole-lane strips exceed the VMEM budget
     # (X very wide), and an x-window would FIT — verified: (512, 64, 256)
-    # lives at ~4.98 GB vs whole-lane ~318 GB.  The picker must decline
+    # lives at ~5.55 GB vs whole-lane ~318 GB.  The picker must decline
     # rather than offer the x-window _stream_gates rejects outright.
     monkeypatch.setattr(sf, "_BZ_LADDER", (512,))
-    monkeypatch.setattr(sf, "_VMEM_LIMIT", 5 * 10**9)
+    monkeypatch.setattr(sf, "_VMEM_LIMIT", 6 * 10**9)
     assert sf._strip_live_bytes(512, 64, 256, 32768, wm, wm_a, 4, 1,
-                                False) < 5 * 10**9  # x-window would fit
+                                False) < 6 * 10**9  # x-window would fit
     assert sf._pick_strip(4096, 4096, 32768, wm, wm_a, 4, 1) is None
 
 
