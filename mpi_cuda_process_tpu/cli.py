@@ -34,6 +34,7 @@ from .parallel import mesh as mesh_lib
 from .parallel import stepper as stepper_lib
 import os
 
+from .obs.spans import region
 from .resilience import faults
 from .utils import checkpointing, diagnostics, native, render
 from .utils.init import init_state, init_state_sharded
@@ -634,6 +635,13 @@ def maybe_auto_fuse(cfg: RunConfig) -> RunConfig:
                 cfg.check_finite, cfg.dump_every]
     if any(v % k for v in cadences if v):
         return cfg
+    with region("sim.auto_fuse_probe"):
+        return _probe_fuse(cfg, k)
+
+
+def _probe_fuse(cfg: RunConfig, k: int) -> RunConfig:
+    """``cfg`` upgraded to ``--fuse k`` when the kernel ``build`` would
+    construct for it builds, else ``cfg``."""
     st = _make_cfg_stencil(cfg)
     if len(cfg.grid) == 2:
         from .ops.pallas.fullgrid import make_fullgrid_step
@@ -758,7 +766,13 @@ def _resume(cfg: RunConfig, targets):
 
 
 def build(cfg: RunConfig):
-    """Materialize (stencil, step_fn, fields, start_step) from a config."""
+    """Materialize (stencil, step_fn, fields, start_step) from a config,
+    inside the ``sim.build`` region."""
+    with region("sim.build"):
+        return _build(cfg)
+
+
+def _build(cfg: RunConfig):
     st = _make_cfg_stencil(cfg)
 
     start_step = 0

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .obs.spans import region
 from .ops.stencil import Fields, Stencil
 from .resilience import faults
 
@@ -399,6 +400,12 @@ def run_simulation(
     with ``--compile-cache`` a shape the machine has seen before skips
     the real XLA work.  ``None`` continues unchanged.
 
+    Each chunk runs inside ``obs/spans.region("sim.chunk",
+    step_num=<its first absolute step>)`` (a ``StepTraceAnnotation``
+    in a ``--profile-dir`` trace), its runner call inside
+    ``sim.runner`` and the callback inside ``sim.observe``: host-side
+    names on the profiler's clock, nothing inside the jitted program.
+
     ``observer`` (telemetry, ``obs/runtime.py``) receives
     ``begin_chunk()`` / ``record_chunk(steps, seconds)`` around each
     chunk, the wall time measured with a ``block_until_ready`` fence.
@@ -419,17 +426,20 @@ def run_simulation(
 
     def _run_chunk(runner, fs, n, abs_step):
         if observer is None:
-            return runner(fs, abs_step)
+            with region("sim.runner"):
+                return runner(fs, abs_step)
         observer.begin_chunk()
         t0 = time.perf_counter()
-        out = jax.block_until_ready(runner(fs, abs_step))
+        with region("sim.runner"):
+            out = jax.block_until_ready(runner(fs, abs_step))
         observer.record_chunk(n, time.perf_counter() - t0)
         return out
 
     if not log_every or (callback is None and observer is None
                          and migrator is None):
-        return _run_chunk(runner_factory(step_fn, n_steps), fields,
-                          n_steps, start_step)
+        with region("sim.chunk", step_num=start_step):
+            return _run_chunk(runner_factory(step_fn, n_steps), fields,
+                              n_steps, start_step)
 
     done = 0
     runners = {}
@@ -439,12 +449,14 @@ def run_simulation(
         chunk = min(boundary - abs_step, n_steps - done)
         if chunk not in runners:
             runners[chunk] = runner_factory(step_fn, chunk)
-        fields = _run_chunk(runners[chunk], fields, chunk, abs_step)
-        done += chunk
-        if callback is not None:
-            replacement = callback(done, fields)
-            if replacement is not None:
-                fields = replacement
+        with region("sim.chunk", step_num=abs_step):
+            fields = _run_chunk(runners[chunk], fields, chunk, abs_step)
+            done += chunk
+            if callback is not None:
+                with region("sim.observe"):
+                    replacement = callback(done, fields)
+                if replacement is not None:
+                    fields = replacement
         if migrator is not None and done < n_steps:
             swap = migrator(done, fields)
             if swap is not None:

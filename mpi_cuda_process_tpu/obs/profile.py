@@ -14,8 +14,10 @@ module, only a roofline prediction.  This module measures it:
   extended by tests/test_obs_profile.py); with it on, ``start_trace``/
   ``stop_trace`` run strictly at chunk boundaries — never inside the
   scan.
-* a parser for the emitted Chrome-trace events
-  (:func:`load_trace_events`) and an attribution pass
+* a reader of the profiler's newest ``.xplane.pb``
+  (:func:`load_trace_events`, through ``jax.profiler.ProfileData``:
+  the leaf ops of every TPU plane, as Chrome-trace-shaped events) and
+  an attribution pass
   (:func:`attribute_events`) that buckets device time into
   interior-compute vs ppermute/collective (the exchange) and computes
   the **measured overlap efficiency**::
@@ -36,57 +38,87 @@ unavailable`` with the reason — never fabricated zeros.
 from __future__ import annotations
 
 import glob
-import gzip
-import json
 import os
+import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 # Event-name classification for the exchange bucket.  ppermute lowers to
 # collective-permute on TPU; the rest cover the collectives any future
-# stepper might issue.  Lowercased substring match.
+# stepper might issue.  Lowercased substring match on the op's own name.
 _COMM_MARKERS = (
     "ppermute", "collective-permute", "collective_permute",
     "all-reduce", "all_reduce", "all-gather", "all_gather",
     "all-to-all", "all_to_all", "reduce-scatter", "reduce_scatter",
     "send", "recv",
 )
+# a TPU op event is named by its whole HLO instruction
+# (``%fusion.3 = f32[8]{0} fusion(... %collective-permute-done.2)``):
+# the instruction's own name and kind, never its operands
+_HLO = re.compile(r"%?([\w.\-]+) = .*?\s([a-z][\w\-]*)\(")
+_DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+_OP_LINES = ("XLA Ops", "Async XLA Ops")
 
 
 def is_comm_event(name: str) -> bool:
-    low = str(name).lower()
-    return any(m in low for m in _COMM_MARKERS)
+    """A collective, judged by the op's own name and kind."""
+    m = _HLO.match(str(name))
+    own = f"{m.group(1)} {m.group(2)}" if m else str(name)
+    low = own.lower()
+    return any(mk in low for mk in _COMM_MARKERS)
 
 
 # ------------------------------------------------------------ trace IO
 
 def find_trace_files(profile_dir: str) -> List[str]:
-    """Chrome-trace files under a ``jax.profiler`` output dir, oldest
-    first (the profiler writes ``plugins/profile/<run>/<host>.trace
-    .json.gz``; plain ``.trace.json`` accepted for synthetic fixtures)."""
-    pats = (os.path.join(profile_dir, "**", "*.trace.json.gz"),
-            os.path.join(profile_dir, "**", "*.trace.json"))
-    found: List[str] = []
-    for pat in pats:
-        found.extend(glob.glob(pat, recursive=True))
+    """``.xplane.pb`` files under a ``jax.profiler`` output dir, oldest
+    first (the profiler writes ``plugins/profile/<run>/<host>.xplane
+    .pb``)."""
+    found = glob.glob(os.path.join(profile_dir, "**", "*.xplane.pb"),
+                      recursive=True)
     return sorted(set(found), key=lambda p: (os.path.getmtime(p), p))
 
 
-def load_trace_events(profile_dir: str) -> List[Dict[str, Any]]:
-    """``traceEvents`` of the NEWEST trace file under ``profile_dir``.
+def _leaves(ops: List[Tuple[str, float, float]]
+            ) -> List[Tuple[str, float, float]]:
+    """The ops that hold no other op: a ``while`` op spans its body's
+    ops, collectives included, and must not count as compute beside
+    them."""
+    ops = sorted(ops, key=lambda o: (o[1], -o[2]))
+    return [o for o, nxt in zip(ops, ops[1:] + [None])
+            if nxt is None or not (nxt[1] < o[2] and nxt[2] <= o[2])]
 
-    Returns ``[]`` when no trace file exists (profiler never ran, or a
-    jax version that emits only ``.xplane.pb``) — the caller degrades
-    to ``attribution: unavailable`` rather than guessing.
+
+def load_trace_events(profile_dir: str) -> List[Dict[str, Any]]:
+    """Events of the NEWEST ``.xplane.pb`` under ``profile_dir``.
+
+    Read with ``jax.profiler.ProfileData``: every plane becomes one pid
+    with a ``process_name`` record; a TPU plane's leaf ops of ``XLA
+    Ops`` and its ``Async XLA Ops`` become complete (``"X"``) events in
+    microseconds.  Returns ``[]`` when no trace file exists — the caller
+    degrades to ``attribution: unavailable`` rather than guessing.
     """
     files = find_trace_files(profile_dir)
     if not files:
         return []
-    path = files[-1]
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as fh:  # type: ignore[operator]
-        doc = json.load(fh)
-    events = doc.get("traceEvents") if isinstance(doc, dict) else doc
-    return events if isinstance(events, list) else []
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(files[-1])
+    events: List[Dict[str, Any]] = []
+    for pid, plane in enumerate(data.planes):
+        events.append({"ph": "M", "pid": pid, "name": "process_name",
+                       "args": {"name": plane.name}})
+        if not _DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            if line.name not in _OP_LINES:
+                continue
+            ops = [(e.name, e.start_ns, e.end_ns) for e in line.events]
+            if line.name == "XLA Ops":
+                ops = _leaves(ops)
+            events.extend({"ph": "X", "pid": pid, "tid": line.name,
+                           "name": n, "ts": s * 1e-3,
+                           "dur": (e - s) * 1e-3} for n, s, e in ops)
+    return events
 
 
 # -------------------------------------------------- interval arithmetic
@@ -226,7 +258,7 @@ def attribution_record(profile_dir: str,
         return rec
     if not events:
         rec.update(attribution="unavailable",
-                   reason="no .trace.json emitted under the profile dir")
+                   reason="no .xplane.pb emitted under the profile dir")
         return rec
     rec.update(attribute_events(events))
     return rec

@@ -13,10 +13,10 @@ What a chunk record carries:
 
 * wall seconds and ms/step (in REAL steps: the recorder knows the
   ``--fuse`` step unit);
-* a recompile flag — ``jax.monitoring``'s backend-compile events are
-  counted process-wide, so a chunk that triggered a compile AFTER the
-  first chunk (shape drift, cache invalidation, a second chunk size)
-  is marked instead of silently polluting the steady-state percentiles;
+* a recompile flag — read from the process's compile counter (below),
+  so a chunk that triggered a compile AFTER the first chunk (shape
+  drift, cache invalidation, a second chunk size) is marked instead of
+  silently polluting the steady-state percentiles;
 * ``device.memory_stats()`` peaks when the backend reports them (TPU
   does; CPU returns None and the field is omitted).
 
@@ -28,37 +28,94 @@ roofline prediction.
 
 from __future__ import annotations
 
+import collections
+import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-# Process-wide compile counter via jax.monitoring.  Registration is
-# one-way (jax offers no targeted unregister), so one module-level
-# listener serves every recorder; each recorder diffs the counter.
-_COMPILE_EVENT_SUFFIX = "backend_compile_duration"
-_compile_events = [0]
-_listener_on = [False]
+from .spans import current_region
+
+# ------------------------------------------------------ compile counter
+#
+# One jax.monitoring listener for the whole program, registered when
+# this module is imported (``obs/__init__`` imports it, so any import
+# of the obs layer — the driver and the diagnostics import
+# ``obs.spans`` — registers it before the program's first compile).
+# The duration events it reads, as JAX 0.9 fires them:
+#
+# * ``/jax/core/compile/backend_compile_duration`` — once per backend
+#   compile request, a persistent-cache hit included (the hit is
+#   served inside it);
+# * ``/jax/compilation_cache/cache_retrieval_time_sec`` — before that
+#   event, on the same thread, when the request was a cache load.
+#
+# So each backend event is one compile or one load, and the two never
+# double count.  Each is keyed by the innermost ``spans.region`` open
+# on the compiling thread (``"(none)"`` outside any).
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+NO_REGION = "(none)"
+_COMPILE_LOG_KEEP = 16384
+
+_counter_lock = threading.Lock()
+_load_pending = threading.local()
+_totals: Dict[str, List[float]] = {}  # region -> [compiles, loads, s]
+# (perf_counter at the end of the event, region, is_load, seconds)
+_compile_log: "collections.deque[Tuple[float, str, bool, float]]" = \
+    collections.deque(maxlen=_COMPILE_LOG_KEEP)
 
 
 def _on_duration(event: str, duration: float, **_kw: Any) -> None:
-    if event.endswith(_COMPILE_EVENT_SUFFIX):
-        _compile_events[0] += 1
-
-
-def _ensure_compile_listener() -> None:
-    if _listener_on[0]:
+    if event == CACHE_LOAD_EVENT:
+        _load_pending.flag = True
         return
-    try:
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        _listener_on[0] = True
-    except Exception:  # noqa: BLE001 — recompile detection is best-effort
-        pass
+    if event != BACKEND_COMPILE_EVENT:
+        return
+    is_load = getattr(_load_pending, "flag", False)
+    _load_pending.flag = False
+    where = current_region() or NO_REGION
+    with _counter_lock:
+        tot = _totals.setdefault(where, [0, 0, 0.0])
+        tot[1 if is_load else 0] += 1
+        tot[2] += float(duration)
+        _compile_log.append((time.perf_counter(), where, is_load,
+                             float(duration)))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def compile_counts(since: Optional[float] = None,
+                   until: Optional[float] = None
+                   ) -> Dict[str, Dict[str, float]]:
+    """``{region: {"compiles", "loads", "seconds"}}`` for this process.
+
+    With ``since``/``until`` (``time.perf_counter`` seconds), only the
+    events that ended in ``[since, until)``, from the last
+    ``_COMPILE_LOG_KEEP`` events."""
+    with _counter_lock:
+        if since is None and until is None:
+            rows = {k: tuple(v) for k, v in _totals.items()}
+        else:
+            lo = float("-inf") if since is None else since
+            hi = float("inf") if until is None else until
+            acc: Dict[str, List[float]] = {}
+            for t, where, is_load, s in _compile_log:
+                if lo <= t < hi:
+                    row = acc.setdefault(where, [0, 0, 0.0])
+                    row[1 if is_load else 0] += 1
+                    row[2] += s
+            rows = {k: tuple(v) for k, v in acc.items()}
+    return {k: {"compiles": int(c), "loads": int(n), "seconds": s}
+            for k, (c, n, s) in rows.items()}
 
 
 def compile_events_seen() -> int:
-    """Backend compiles observed in this process (0 if unavailable)."""
-    return _compile_events[0]
+    """Backend compiles and cache loads observed in this process."""
+    with _counter_lock:
+        return int(sum(c + n for c, n, _ in _totals.values()))
 
 
 def device_memory_stats() -> Dict[str, int]:
@@ -123,7 +180,6 @@ class RuntimeRecorder:
         self.recompiles = 0
         self.last_progress = time.monotonic()
         self._chunk_begin_compiles: Optional[int] = None
-        _ensure_compile_listener()
 
     def mark(self) -> None:
         """Record liveness without a chunk (benchmark harness loops)."""

@@ -36,6 +36,33 @@ name           emitted by
                ``queue_wait``, ``result``)
 =============  =====================================================
 
+Every span also goes through :func:`region`, the one primitive that
+puts a name on the profiler's clock: a ``jax.profiler.TraceAnnotation``
+(so a ``--profile-dir`` trace holds the span beside the device ops), a
+per-thread stack of open regions (``obs/runtime``'s compile counter
+keys each compile by the innermost), and a bounded record of closed
+regions on ``time.perf_counter`` (:func:`closed_regions`, what an
+in-process reader such as the benchmark reads).  The program's own
+regions are named ``sim.*``:
+
+==========================  ==========================================
+region                      opened by
+==========================  ==========================================
+``sim.build``               ``cli.build``
+``sim.auto_fuse_probe``     ``cli.maybe_auto_fuse``'s kernel probe
+``sim.chunk``               ``driver.run_simulation``, one per chunk
+                            (a ``StepTraceAnnotation``, ``step_num`` =
+                            the chunk's first absolute step)
+``sim.runner``              the chunk's runner call (fenced when an
+                            observer times it)
+``sim.observe``             the chunk's callback
+``sim.diagnostics``         ``utils/diagnostics.field_diagnostics``
+``sim.diagnostics.stage``   building and dispatching its reductions
+                            and residual
+``sim.diagnostics.fetch``   its one ``jax.device_get``: the wait for
+                            the device
+==========================  ==========================================
+
 Design constraints, inherited from the obs layer:
 
 * **Zero ops in the jitted step** — spans are host-side wall clocks at
@@ -44,24 +71,99 @@ Design constraints, inherited from the obs layer:
 * **Never load-bearing** — emission failures are swallowed; a closed
   trace drops late spans silently.
 * **Pure stdlib** — importable by the supervisor parent on a wedged
-  box without dragging a jax backend in.
+  box without dragging a jax backend in; :func:`region` annotates only
+  when the process has already imported jax.
 * Disable with ``OBS_SPANS=0`` (events keep flowing; only spans stop).
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 ENV_VAR = "OBS_TRACE_CONTEXT"
 SPAN_KIND = "span"
 
 _tls = threading.local()
+
+
+# ------------------------------------------------------------ regions
+
+# closed regions, oldest first: (name, start, end) on time.perf_counter;
+# bounded so a long run keeps the last few thousand chunks' worth
+_CLOSED_KEEP = 16384
+_closed: "collections.deque[Tuple[str, float, float]]" = \
+    collections.deque(maxlen=_CLOSED_KEEP)
+
+
+def _open_regions() -> List[str]:
+    stack = getattr(_tls, "regions", None)
+    if stack is None:
+        stack = _tls.regions = []
+    return stack
+
+
+class region:
+    """``with region(name):`` — a named stretch of host work.
+
+    Opens ``jax.profiler.TraceAnnotation(name)`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is given) if jax is
+    already imported — with no profiler session active that costs one
+    TraceMe check — pushes ``name`` on this thread's stack of open
+    regions, and on exit records ``(name, start, end)`` in
+    :func:`closed_regions`.  A class, not a generator, to keep the
+    per-chunk cost at about a microsecond.
+    """
+
+    __slots__ = ("name", "step_num", "_annotation", "_t0")
+
+    def __init__(self, name: str, step_num: Optional[int] = None):
+        self.name = name
+        self.step_num = step_num
+
+    def __enter__(self) -> "region":
+        _open_regions().append(self.name)
+        jax = sys.modules.get("jax")
+        if jax is None:
+            self._annotation = None
+        elif self.step_num is None:
+            self._annotation = jax.profiler.TraceAnnotation(self.name)
+        else:
+            self._annotation = jax.profiler.StepTraceAnnotation(
+                self.name, step_num=self.step_num)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        stack = _open_regions()
+        if stack:
+            stack.pop()
+        _closed.append((self.name, self._t0, t1))
+
+
+def current_region() -> Optional[str]:
+    """This thread's innermost open region, or None."""
+    stack = getattr(_tls, "regions", None)
+    return stack[-1] if stack else None
+
+
+def closed_regions(name: Optional[str] = None
+                   ) -> List[Tuple[str, float, float]]:
+    """The last closed regions (all, or those called ``name``), oldest
+    first, as ``(name, start, end)`` in ``time.perf_counter`` seconds."""
+    return [r for r in list(_closed) if name is None or r[0] == name]
 
 
 def new_id() -> str:
@@ -274,25 +376,27 @@ class SpanEmitter:
     def span(self, name: str, **attrs: Any) -> Iterator[Optional[SpanContext]]:
         """Open a span around a code block; emitted at exit with the
         measured duration.  Yields the span's context (what a launcher
-        encodes into a child's ``OBS_TRACE_CONTEXT``)."""
-        if not self.enabled or self.trace is None:
-            yield None
-            return
-        parent = self.current().span_id
-        ctx = SpanContext(self.trace_id, new_id())
-        stack = self._stack()
-        stack.append(ctx)
-        start = time.time()
-        t0 = time.monotonic()
-        try:
-            yield ctx
-        finally:
-            if stack and stack[-1] is ctx:
-                stack.pop()
-            rec = make_span_record(name, self.trace_id, ctx.span_id,
-                                   parent, start, time.monotonic() - t0,
-                                   attrs or None)
-            self._write(rec)
+        encodes into a child's ``OBS_TRACE_CONTEXT``).  The block runs
+        inside :func:`region` of the same name, emitted or not."""
+        with region(name):
+            if not self.enabled or self.trace is None:
+                yield None
+                return
+            parent = self.current().span_id
+            ctx = SpanContext(self.trace_id, new_id())
+            stack = self._stack()
+            stack.append(ctx)
+            start = time.time()
+            t0 = time.monotonic()
+            try:
+                yield ctx
+            finally:
+                if stack and stack[-1] is ctx:
+                    stack.pop()
+                rec = make_span_record(name, self.trace_id, ctx.span_id,
+                                       parent, start,
+                                       time.monotonic() - t0, attrs or None)
+                self._write(rec)
 
     def close(self, **attrs: Any) -> None:
         """Emit the root span (idempotent).  Call BEFORE the trace

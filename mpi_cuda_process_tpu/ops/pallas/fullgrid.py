@@ -206,6 +206,7 @@ def _build_call(stencil, block_shape, m, k, interpret, sharded_global=None,
         if with_origin else []
     call = pl.pallas_call(
         kernel,
+        name="fused_fullgrid",
         grid=(),
         in_specs=extra_specs + [in_spec] * nfields,
         out_specs=[out_spec] * nfields,

@@ -676,6 +676,7 @@ def build_yzslab_padfree_call(
             _fused_yzslab_kernel, micro, nfields, k, margin, halo, bz, by,
             (gz, gy, gx), periodic, stencil.parity_sensitive, Lz // bz,
             Y // by, interpret),
+        name="fused_yzslab_padfree",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + per_field * nfields,
@@ -782,6 +783,7 @@ def build_zslab_xwin_call(
             _fused_zslab_xwin_kernel, micro, nfields, k, margin, halo,
             bz, by, bx, (gz, gy, gxx), periodic,
             stencil.parity_sensitive, Lz // bz, interpret),
+        name="fused_zslab_xwin",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + per_field * nfields,
@@ -1012,6 +1014,7 @@ def build_yzslab_xwin_call(
             _fused_yzslab_xwin_kernel, micro, nfields, k, margin, halo,
             bz, by, bx, (gz, gy, gxx), periodic,
             stencil.parity_sensitive, Lz // bz, Y // by, interpret),
+        name="fused_yzslab_xwin",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + per_field * nfields,
@@ -1078,6 +1081,7 @@ def build_zslab_padfree_call(
             _fused_zslab_kernel, micro, nfields, k, margin, halo, bz, by,
             (gz, gy, gx), periodic, stencil.parity_sensitive, Lz // bz,
             interpret),
+        name="fused_zslab_padfree",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + per_field * nfields,
@@ -1269,6 +1273,7 @@ def build_fused_call(
 
     call = pl.pallas_call(
         kernel,
+        name="fused_padfree" if padfree else "fused_padded",
         grid=grid,
         in_specs=extra_specs + per_field_specs * nfields,
         out_specs=[out_spec] * nfields,

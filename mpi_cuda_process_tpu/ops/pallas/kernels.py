@@ -205,6 +205,7 @@ def _heat3d_compute(stencil: Stencil, interpret: bool):
         zc, ztail, so = _zchunk_specs(p.shape, bz, halo)
         res = pl.pallas_call(
             functools.partial(_zchunk_kernel, taps, bz),
+            name="zchunk_taps",
             grid=(z // bz,),
             in_specs=[zc, ztail],
             out_specs=so,
@@ -231,6 +232,7 @@ def _wave3d_compute(stencil: Stencil, interpret: bool):
         sprev = pl.BlockSpec((bz, y, x), lambda i: (i, 0, 0))
         new_u = pl.pallas_call(
             functools.partial(_zchunk_wave_kernel, c2dt2, bz),
+            name="zchunk_wave",
             grid=(z // bz,),
             in_specs=[zc, ztail, sprev],
             out_specs=so,
@@ -284,6 +286,7 @@ def _whole2d_compute(stencil: Stencil, interpret: bool):
             return stencil.update(padded)  # too big for VMEM: jnp path
         res = pl.pallas_call(
             body,
+            name="whole2d",
             out_shape=jax.ShapeDtypeStruct(out_shape, p.dtype),
             interpret=interpret,
         )(p)

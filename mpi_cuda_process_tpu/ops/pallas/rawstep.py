@@ -278,6 +278,7 @@ def make_raw_step(
         call = pl.pallas_call(
             functools.partial(
                 _wave_kernel, c2dt2, bz, (Z, Y, X), interpret),
+            name="rawstep_wave",
             grid=(Z // bz,),
             in_specs=[prev_p, cur, next_p, sprev],
             out_specs=out,
@@ -306,6 +307,7 @@ def make_raw_step(
             functools.partial(
                 _grayscott_kernel, float(p["du"]), float(p["dv"]),
                 float(p["f"]), float(p["kappa"]), bz, (Z, Y, X), interpret),
+            name="rawstep_grayscott",
             grid=(Z // bz,),
             in_specs=[prev_p, cur, next_p, prev_p, cur, next_p],
             out_specs=[out, out],
@@ -333,6 +335,7 @@ def make_raw_step(
     out = pl.BlockSpec((bz, Y, X), lambda i: (i, 0, 0))
     call = pl.pallas_call(
         functools.partial(_heat_kernel, taps, bz, halo, (Z, Y, X)),
+        name="rawstep_taps",
         grid=(Z // bz,),
         in_specs=[prev_p, cur, next_p],
         out_specs=out,

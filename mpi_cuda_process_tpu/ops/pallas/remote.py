@@ -299,6 +299,7 @@ def build_ring_exchange_call(
             **({"collective_id": int(collective_id)} if remote else {}))
     call = pl.pallas_call(
         kernel,
+        name="halo_ring_dma",
         in_specs=in_specs,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
         out_shape=[jax.ShapeDtypeStruct(shape, dtype)] * 2,

@@ -672,6 +672,7 @@ def build_stream_sharded_call(
         (X // bx, Y // by) if order == "xy" else (Y // by, X // bx))
     call = pl.pallas_call(
         kernel,
+        name="fused_stream_zslab",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * (3 * nfields),
@@ -751,6 +752,7 @@ def build_stream_2axis_call(
         (X // bx, Ly // by) if order == "xy" else (Ly // by, X // bx))
     pallas = pl.pallas_call(
         kernel,
+        name="fused_stream_yz",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * (9 * nfields),
@@ -841,6 +843,7 @@ def make_stream_fused_step(
         (X // bx, Y // by) if order == "xy" else (Y // by, X // bx))
     call = pl.pallas_call(
         kernel,
+        name="fused_stream",
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nfields,
         out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nfields,

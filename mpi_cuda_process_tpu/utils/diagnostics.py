@@ -21,6 +21,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from ..obs.spans import region
 from ..ops.stencil import Stencil
 
 
@@ -45,10 +46,20 @@ def _staged_diagnostics(stencil: Stencil, fields, step_fn=None):
 
 
 def field_diagnostics(stencil: Stencil, fields, step_fn=None) -> Dict[str, float]:
-    """All metrics for one logging interval — ONE host transfer total."""
-    staged = _staged_diagnostics(stencil, fields, step_fn=step_fn)
-    fetched = jax.device_get(staged)  # batched: one round-trip for all
-    return {k: float(v) for k, v in fetched.items()}
+    """All metrics for one logging interval — ONE host transfer total.
+
+    Regions (``obs/spans.region``): ``sim.diagnostics`` around the call,
+    ``sim.diagnostics.stage`` around building and dispatching the
+    reductions (and the residual step: on a mesh it runs op by op, each
+    op dispatched — and compiled or loaded — here), and
+    ``sim.diagnostics.fetch`` around the transfer, the wait for the
+    device."""
+    with region("sim.diagnostics"):
+        with region("sim.diagnostics.stage"):
+            staged = _staged_diagnostics(stencil, fields, step_fn=step_fn)
+        with region("sim.diagnostics.fetch"):
+            fetched = jax.device_get(staged)  # batched: one round-trip
+        return {k: float(v) for k, v in fetched.items()}
 
 
 def _residual_scalar(step_fn, fields):

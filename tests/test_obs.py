@@ -346,7 +346,8 @@ def test_recorder_separates_compile_flags_recompiles_and_percentiles():
     # an injected compile event mid-steady-state flags that chunk and
     # excludes it from the percentiles
     rec.begin_chunk()
-    runtime._compile_events[0] += 3
+    for _ in range(3):
+        runtime._on_duration(runtime.BACKEND_COMPILE_EVENT, 0.0)
     chunk = rec.record_chunk(2, 5.0)
     assert chunk["recompiled"] is True
     s2 = rec.summary()

@@ -1,6 +1,7 @@
 """Tests for device-trace attribution (mpi_cuda_process_tpu/obs/profile).
 
-All on synthetic Chrome-trace fixtures — no TPU required.  Pins:
+All on synthetic fixtures (trace events, and one ``.xplane.pb`` built
+from a text proto) — no TPU required.  Pins:
 
 * **parser buckets** — device-lane selection (host lanes never counted),
   comm-vs-compute classification, interval-union math with nested and
@@ -18,8 +19,6 @@ All on synthetic Chrome-trace fixtures — no TPU required.  Pins:
   ``--profile-dir`` combinations.
 """
 
-import gzip
-import json
 import os
 import sys
 
@@ -129,22 +128,59 @@ def test_device_lane_without_events_is_unavailable():
 
 # -------------------------------------------------------------- file IO
 
+_XSPACE = """
+planes {
+  id: 1
+  name: "/device:TPU:0"
+  lines { id: 1 name: "XLA Ops" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 10000000 }
+    events { metadata_id: 2 offset_ps: 0 duration_ps: 4000000 }
+    events { metadata_id: 3 offset_ps: 4000000 duration_ps: 6000000 } }
+  lines { id: 2 name: "Async XLA Ops" timestamp_ns: 0
+    events { metadata_id: 4 offset_ps: 8000000 duration_ps: 4000000 } }
+  event_metadata { key: 1 value { id: 1
+    name: "%while.1 = (f32[8]) while(f32[8] %p), body=%b" } }
+  event_metadata { key: 2 value { id: 2
+    name: "%fusion.1 = f32[8] fusion(f32[8] %p)" } }
+  event_metadata { key: 3 value { id: 3
+    name: "%fusion.2 = f32[8] fusion(f32[8] %collective-permute-done.1)" } }
+  event_metadata { key: 4 value { id: 4
+    name: "%collective-permute-start.1 = (f32[8]) collective-permute-start(f32[8] %fusion.2)" } }
+}
+planes { id: 2 name: "/host:CPU"
+  lines { id: 1 name: "python" timestamp_ns: 0
+    events { metadata_id: 1 offset_ps: 0 duration_ps: 50000000 } }
+  event_metadata { key: 1 value { id: 1 name: "sim.chunk" } }
+}
+"""
+
+
 def test_load_trace_events_gz_roundtrip(tmp_path):
+    """The newest .xplane.pb read back: leaf ops only (the while op
+    holding the fusions drops out), collectives judged by their own
+    name (a fusion reading a collective's output is compute)."""
+    from jax.profiler import ProfileData
+
     run_dir = tmp_path / "plugins" / "profile" / "2026_08_04"
     run_dir.mkdir(parents=True)
-    doc = {"traceEvents": _trace([_ev(1, "fusion", 0, 5)])}
-    with gzip.open(run_dir / "host.trace.json.gz", "wt") as fh:
-        json.dump(doc, fh)
+    (run_dir / "host.xplane.pb").write_bytes(
+        ProfileData.text_proto_to_serialized_xspace(_XSPACE))
     events = profile.load_trace_events(str(tmp_path))
-    assert any(e.get("name") == "fusion" for e in events)
+    ops = [e for e in events if e["ph"] == "X"]
+    assert [e["name"].split(" ")[0] for e in ops] == [
+        "%fusion.1", "%fusion.2", "%collective-permute-start.1"]
     att = profile.attribution_record(str(tmp_path), profiled_chunk=1)
     assert att["attribution"] == "ok" and att["profiled_chunk"] == 1
+    # compute 0-10 us, the collective 8-12 us: 2 us of it exposed
+    assert att["compute_us"] == pytest.approx(10.0)
+    assert att["comm_us"] == pytest.approx(4.0)
+    assert att["exposed_comm_us"] == pytest.approx(2.0)
 
 
 def test_attribution_record_degradations(tmp_path):
     empty = profile.attribution_record(str(tmp_path), profiled_chunk=1)
     assert empty["attribution"] == "unavailable"
-    assert "no .trace.json" in empty["reason"]
+    assert "no .xplane.pb" in empty["reason"]
 
     never = profile.attribution_record(str(tmp_path), profiled_chunk=None)
     assert never["attribution"] == "unavailable"

@@ -175,7 +175,9 @@ def test_session_adopts_env_context_and_disable_gate(
 
 def test_jitted_step_identical_spans_on_vs_off(tmp_path, monkeypatch):
     """Acceptance criterion: the step jaxpr is byte-identical with spans
-    on vs off — spans are host-side wall clocks only."""
+    on vs off — spans are host-side wall clocks only — and the step
+    jaxpr and the runner's HLO are identical traced inside the program's
+    regions (``spans.region``: profiler annotations, no ops) or not."""
     import jax
 
     from mpi_cuda_process_tpu import driver, obs
@@ -202,6 +204,17 @@ def test_jitted_step_identical_spans_on_vs_off(tmp_path, monkeypatch):
                             driver.make_runner(step, 4, jit=False))(
                             abstract)))
     assert jaxprs["1"] == jaxprs["0"]
+
+    def programs():
+        runner = jax.jit(driver.make_runner(step, 4, jit=False))
+        return (str(jax.make_jaxpr(step)(abstract)),
+                runner.lower(abstract).as_text())
+
+    plain = programs()
+    with spans.region("sim.chunk", step_num=0), spans.region("sim.runner"):
+        inside = programs()
+    assert inside == plain
+    assert plain[0] == jaxprs["0"][0]
     # spans-on really did emit (the comparison is not vacuous)
     on = _read(str(tmp_path / "sp1.jsonl"))
     assert any(r["kind"] == "span" and r["name"] == "compile"
