@@ -57,8 +57,9 @@ region                      opened by
                             observer times it)
 ``sim.observe``             the chunk's callback
 ``sim.diagnostics``         ``utils/diagnostics.field_diagnostics``
-``sim.diagnostics.stage``   building and dispatching its reductions
-                            and residual
+``sim.diagnostics.stage``   the one dispatch of its compiled
+                            observation program (reductions and
+                            residual together)
 ``sim.diagnostics.fetch``   its one ``jax.device_get``: the wait for
                             the device
 ==========================  ==========================================
