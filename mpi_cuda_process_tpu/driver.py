@@ -163,7 +163,7 @@ def pipeline_hooks(step_fn):
 
 
 def make_runner(step_fn, n_steps: int, jit: bool = True):
-    """Wrap ``step_fn`` in a donated, jitted ``lax.scan`` over ``n_steps``.
+    """Wrap ``step_fn`` in a jitted ``lax.scan`` over ``n_steps``.
 
     Donation of the carry means the two time levels reuse the same buffers —
     the free equivalent of the reference's (intended) d_univ/d_new_univ swap.
@@ -178,6 +178,17 @@ def make_runner(step_fn, n_steps: int, jit: bool = True):
     every p steps) advances p steps per scan iteration, so the body ends
     with every buffer where it began and XLA copies none back into the
     carry; steps past the last multiple of p run after the scan.
+
+    A step that writes out of place (``step_fn._out_of_place``: the
+    pad-free fused pass reads overlapping windows of its input while it
+    writes its output) cannot write the donated buffer it reads, so XLA
+    would copy the whole state into a temporary before every pass.  Its
+    runner takes p passes per iteration only when ``n_steps`` is a
+    multiple of p: the carry then ping-pongs between the donated buffer
+    and one temporary.  A lone pass is not donated (its output beside
+    the input: no copy, the same peak).  Any other count runs one pass
+    per iteration, a copy each, since pairing would need a second
+    temporary.
     """
     # Fault point (resilience/faults.py): the scan is about to be built
     # and jitted — the host-side stand-in for "the compile hung" (fires
@@ -186,6 +197,11 @@ def make_runner(step_fn, n_steps: int, jit: bool = True):
     faults.maybe_fire("compile")
     seed, advance = pipeline_hooks(step_fn)
     period = getattr(step_fn, "_carry_period", 1)
+    donate = True
+    if getattr(step_fn, "_out_of_place", False):
+        donate = n_steps != 1
+        if n_steps % period:
+            period = 1
 
     def run(fields: Fields) -> Fields:
         def body(carry, _):
@@ -200,7 +216,7 @@ def make_runner(step_fn, n_steps: int, jit: bool = True):
         return carry[0]
 
     if jit:
-        run = jax.jit(run, donate_argnums=0)
+        run = jax.jit(run, donate_argnums=0 if donate else ())
     return run
 
 

@@ -1378,6 +1378,12 @@ def make_fused_step(
             args = [f for f in fields for _ in range(9)]
             return tuple(call(*args))
 
+        # Its windows overlap its input, so it cannot write in place: the
+        # runner ping-pongs two passes between the donated buffer and one
+        # temporary (driver.make_runner).  The padded pass below reads its
+        # pad transient, so its output lands in the donated buffer already.
+        step_k._carry_period = 2
+        step_k._out_of_place = True
         return step_k
 
     pad_mode = "wrap" if periodic else "constant"

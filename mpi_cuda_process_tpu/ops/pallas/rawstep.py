@@ -308,6 +308,7 @@ def make_raw_step(
             return (new_u, u)  # carry_map semantics: new u_prev is old u
 
         step._carry_period = 2
+        step._out_of_place = False  # the runner must donate u_prev
         return step
 
     if stencil.name == "grayscott3d":

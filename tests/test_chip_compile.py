@@ -88,6 +88,38 @@ def test_main_path_fused_heat3d_1024(one_chip):
     _check(compiled)
 
 
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 16])
+def test_fused_heat3d_1024_runner_copies_no_state(one_chip, n):
+    """The pad-free pass cannot write the buffer it reads.  Donated one
+    pass per scan iteration, XLA copied the 4 GiB state into a temporary
+    before every pass.  An even count now ping-pongs two passes per
+    iteration between the donated buffer and one temporary, and a lone
+    pass is not donated: no whole-state copy.  An odd count of 3 or more
+    keeps one pass per iteration, and every count holds the 8 GiB peak
+    (state plus one temporary) that one pass per iteration had."""
+    from mpi_cuda_process_tpu.ops.pallas.fused import (
+        make_fused_step, prefer_padfree,
+    )
+
+    st = make_stencil("heat3d")
+    grid = (1024, 1024, 1024)
+    step = make_fused_step(st, grid, 4, interpret=False,
+                           padfree=prefer_padfree(st, grid))
+    compiled = make_runner(step, n).lower(
+        _fields(st, grid, one_chip)).compile()
+    text = compiled.as_text()
+    assert _peak_bytes(compiled) <= 8 * 1024**3 + 2**20, \
+        _peak_bytes(compiled)
+    state = r"f32\[1024,1024,1024\]\{[^}]*\} "
+    kernels = re.findall(state + r"custom-call\(", text)
+    copies = re.findall(state + r"copy(-start)?\(", text)
+    if n > 1 and n % 2:
+        assert len(kernels) == 1
+    else:
+        assert len(kernels) == min(n, 2)
+        assert not copies
+
+
 def test_raw_wave3d_512(one_chip):
     from mpi_cuda_process_tpu.ops.pallas.rawstep import make_raw_step
 

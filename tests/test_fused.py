@@ -87,6 +87,33 @@ def test_fused_in_scan_runner(_k=4, _n=3):
     assert jnp.allclose(out[0], ref[0], atol=1e-5)
 
 
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 5])
+@pytest.mark.parametrize("padfree", [False, True], ids=["padded", "padfree"])
+def test_fused_runner_matches_plain_steps(padfree, n):
+    """The pad-free pass writes out of place: its runner ping-pongs two
+    passes per scan iteration for an even count, runs a lone pass
+    undonated, and one pass per iteration otherwise.  The padded pass reads
+    its pad transient and keeps the plain donated runner.  Each gives the
+    state of n*k plain steps, bit for bit; only the lone pad-free pass
+    leaves the caller's input alive."""
+    st = make_stencil("heat3d")
+    shape, k = (16, 16, 128), 4
+    fields = init_state(st, shape, seed=13, kind="random")
+    step = jax.jit(make_step(st, shape))
+    ref = fields
+    for _ in range(n * k):
+        ref = step(ref)
+    fused = make_fused_step(st, shape, k, interpret=True, padfree=padfree)
+    assert getattr(fused, "_out_of_place", False) == padfree
+    given = tuple(jnp.copy(f) for f in fields)
+    out = make_runner(fused, n)(given)
+    assert jnp.array_equal(out[0], ref[0])
+    kept = padfree and n == 1
+    assert given[0].is_deleted() != kept
+    if kept:
+        assert jnp.array_equal(given[0], fields[0])
+
+
 def test_fused_frame_stays_pinned():
     st = make_stencil("heat3d")
     shape = (16, 16, 128)
