@@ -3,9 +3,10 @@
 Reads ``benchmarks/results_r05.json`` (or ``--in FILE``) and prints the
 markdown table body: one row per successful label, grouped by stencil
 family then grid size, with the ``--compute auto`` policy pick bolded via
-the live cli policy tables — so the measured table and the shipping policy
-can never silently disagree.  Errored/suspect labels are listed beneath
-the table with their reasons (a pending row is information too).
+the live policy tables (``plan.auto_path``) — so the measured table and
+the shipping policy can never silently disagree.  Errored/suspect labels
+are listed beneath the table with their reasons (a pending row is
+information too).
 
 Usage: python benchmarks/mktable.py [--in FILE]
 """
@@ -14,34 +15,12 @@ from __future__ import annotations
 
 import argparse
 import json
-import math
 import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-
-def _auto_pick(stencil: str, grid, dtype: str | None) -> str | None:
-    """The compute string cli's auto policy would select (best-effort)."""
-    from mpi_cuda_process_tpu.cli import (
-        _AUTO_FUSE_K,
-        _AUTO_FUSE_K_BF16,
-        _CLIFF_CELLS,
-        _RAW_ABOVE_CLIFF,
-        _RAW_WINS,
-    )
-
-    bf16 = dtype == "bfloat16"
-    k = (_AUTO_FUSE_K_BF16 if bf16 else _AUTO_FUSE_K).get(stencil)
-    if k:
-        return f"fused{k}"
-    if bf16:
-        return "jnp"
-    if stencil in _RAW_WINS:
-        return "raw"
-    if stencil in _RAW_ABOVE_CLIFF and math.prod(grid) >= _CLIFF_CELLS:
-        return "raw"
-    return "jnp"
+from mpi_cuda_process_tpu import plan  # noqa: E402
 
 
 def main():
@@ -81,7 +60,7 @@ def main():
         dshort = {"float32": "f32", "bfloat16": "bf16",
                   None: "i32" if stencil == "life" else "f32"}.get(
             dtype, dtype)
-        pick = _auto_pick(stencil, grid, dtype)
+        pick = plan.auto_path(stencil, grid, dtype)
         cstr = f"**{compute}**" if compute == pick else compute
         mcstr = f"**{mc:,.0f}**" if compute == pick else f"{mc:,.0f}"
         rows.append((label,

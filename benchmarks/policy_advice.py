@@ -1,10 +1,10 @@
 """Turn the measurement campaign's results into policy-table advice.
 
 Reads ``benchmarks/results_r05.json`` (or ``--in FILE``) and prints, for
-every auto-policy decision the cli keeps as an explicit data table, the
+every auto-policy decision ``plan.py`` keeps as an explicit data table, the
 recommendation the measured numbers support — with the winning/losing
 labels and their Mcells/s cited, so each flip stays a reviewed one-line
-edit in ``cli.py`` rather than a blind paste.  Decisions covered
+edit in ``plan.py`` rather than a blind paste.  Decisions covered
 (docs/STATE.md runbook step 2):
 
 - ``_AUTO_FUSE_KIND``  — stream vs tiled/padfree per 3D family;
@@ -19,7 +19,7 @@ edit in ``cli.py`` rather than a blind paste.  Decisions covered
   calibration).
 
 Pure file-reading + arithmetic: NEVER contacts the backend, safe on a
-lost chip.  The output is advice — the cli tables stay the source of
+lost chip.  The output is advice — the plan tables stay the source of
 truth and every edit should cite its label, as in rounds 3-4.
 
 Usage: python benchmarks/policy_advice.py [--in FILE] [--json]
@@ -106,8 +106,8 @@ def _best(entries, prefixes):
 
 def _size_verdicts(table, family, dtype, pick_a, pick_b):
     """Per-size (size, best_a, best_b) rows wherever BOTH sides measured
-    — family-wide flips must survive every measured size (the cli tables
-    are per-family, not per-size; cli._AUTO_FUSE_K's own rule is 'the
+    — family-wide flips must survive every measured size (the plan tables
+    are per-family, not per-size; plan._AUTO_FUSE_K's own rule is 'the
     fastest measured path at every size')."""
     rows = []
     for (f, size, dt), entries in sorted(table.items()):
@@ -127,7 +127,7 @@ def _ev(rows):
 def _winning_k(rows):
     """The k to recommend family-wide, or None when the winning compute's
     k differs across sizes (a family flip then needs a per-size policy,
-    not one k — cli's rule is 'fastest measured path at EVERY size')."""
+    not one k — plan's rule is 'fastest measured path at EVERY size')."""
     ks = set()
     for _, a, _ in rows:
         m = re.search(r"(\d+)", a[0])

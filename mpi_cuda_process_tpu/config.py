@@ -159,7 +159,7 @@ class RunConfig:
     serve_port: Optional[int] = None
     # JAX persistent compilation cache directory (--compile-cache DIR;
     # JAX_COMPILATION_CACHE_DIR wins, <checkout>/.jax_cache is the
-    # default — cli.enable_compile_cache).  Lifecycle: the cache changes
+    # default — plan.enable_compile_cache).  Lifecycle: the cache changes
     # when a program compiles, never what it computes.
     compile_cache: Optional[str] = None
     # resident serving engine (serving/): --serve-engine PORT runs this

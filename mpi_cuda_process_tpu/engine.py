@@ -1,7 +1,7 @@
 """Request-lifecycle layer: an async submit/handle API over the core.
 
 The split this module completes (round 15, ROADMAP item 2): the
-simulation core — ``cli.build`` constructing a stepper from SIMULATION
+simulation core — ``plan.build`` constructing a stepper from SIMULATION
 fields, ``driver.run_simulation`` advancing state — knows nothing about
 requests; this module owns everything request-shaped: identity, queuing,
 background execution, telemetry wiring, and live status.  The boundary
@@ -11,7 +11,7 @@ the partition test fails).
 
 Usage — submit a config, get a handle, stream chunk telemetry::
 
-    eng = SimulationEngine()
+    eng = SimulationEngine(cli.run)
     h = eng.submit(RunConfig(stencil="heat3d", grid=(64, 64, 128),
                              iters=100, ensemble=8, log_every=10))
     h.status()            # live: manifest, latest chunk, per-member
@@ -20,12 +20,13 @@ Usage — submit a config, get a handle, stream chunk telemetry::
     for ev in h.events(after=0): ...   # raw obs records, seq-ordered
     fields, mcells = h.result()        # blocks; re-raises run errors
 
-Every handle runs the ONE ordinary CLI path (``cli.run``) in a daemon
-thread with telemetry forced on (a derived path when the request did
-not name one — the same discipline as the supervisor's forced
-telemetry), so the chunk stream a handle exposes is the exact obs/
-vocabulary every other tool reads, and a handle's run can be watched
-remotely by pointing ``obs/serve.py`` (or ``--serve``) at its log.
+Every handle runs the run function the engine was given — the ONE
+ordinary CLI path, ``cli.run`` — in a daemon thread with telemetry
+forced on (a derived path when the request did not name one — the same
+discipline as the supervisor's forced telemetry), so the chunk stream a
+handle exposes is the exact obs/ vocabulary every other tool reads, and
+a handle's run can be watched remotely by pointing ``obs/serve.py`` (or
+``--serve``) at its log.
 Batched requests (``ensemble=N``) stream per-member throughput: the
 chunk records carry the member count, and :meth:`RunHandle.status`
 reports aggregate AND per-member Gcells/s (``obs/metrics.RunMetrics``).
@@ -43,7 +44,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from .config import RunConfig, SIM_FIELDS, sim_signature
 
@@ -223,18 +224,21 @@ class SimulationEngine:
 
     One engine serializes execution over the process's device set (the
     run lock); handles queue FIFO.  The engine neither copies nor
-    re-validates simulation semantics — ``cli.run`` stays the single
+    re-validates simulation semantics — ``run`` (``cli.run``: this
+    module sits below the CLI and is handed it) stays the single
     execution path, so submit/handle runs behave byte-for-byte like the
-    equivalent command line (auto-fuse, budget guard, pallas retry,
-    epilogue included).
+    equivalent command line (auto-fuse, budget guard, epilogue
+    included).
     """
 
     _ids = itertools.count()
 
-    def __init__(self, telemetry_dir: Optional[str] = None):
+    def __init__(self, run: Callable[[RunConfig], Tuple],
+                 telemetry_dir: Optional[str] = None):
         from .obs import trace as trace_lib
         from .obs.metrics import MetricsRegistry
 
+        self._run = run
         self.telemetry_dir = telemetry_dir or \
             trace_lib.default_telemetry_dir()
         self._run_lock = threading.Lock()
@@ -304,20 +308,20 @@ class SimulationEngine:
         return handle
 
     def _execute(self, handle: RunHandle) -> None:
-        from . import cancellation, cli
+        from . import cancellation
         from .obs import spans as spans_lib
 
         with self._run_lock:
             handle.started_at = time.time()
             # in-process trace propagation: the run's session (opened
-            # inside cli.run on THIS thread) adopts the request context
+            # inside the run on THIS thread) adopts the request context
             spans_lib.push_thread_context(spans_lib.SpanContext(
                 handle.trace_id, handle.request_span_id))
             try:
                 # the handle's cancel token rides the run thread; the
                 # CLI's chunk callback polls it (cancellation.check)
                 with cancellation.scope(handle._cancel):
-                    handle._result = cli.run(handle.config)
+                    handle._result = self._run(handle.config)
             except BaseException as e:  # noqa: BLE001 — delivered via
                 handle._error = e       # handle.result(), never lost
             finally:

@@ -48,8 +48,8 @@ regions are named ``sim.*``:
 ==========================  ==========================================
 region                      opened by
 ==========================  ==========================================
-``sim.build``               ``cli.build``
-``sim.auto_fuse_probe``     ``cli.maybe_auto_fuse``'s kernel probe
+``sim.build``               ``plan.build``
+``sim.auto_fuse_probe``     ``plan.maybe_auto_fuse``'s kernel probe
 ``sim.chunk``               ``driver.run_simulation``, one per chunk
                             (a ``StepTraceAnnotation``, ``step_num`` =
                             the chunk's first absolute step)

@@ -632,7 +632,7 @@ def make_sharded_fused_step(
             return step
         if forced_padfree:
             # a FORCED kind must never silently measure the padded
-            # kernel under a pad-free label: callers (cli) raise
+            # kernel under a pad-free label: callers (plan.build) raise
             return None
         if pipeline:
             # a requested pipeline must never silently run the padded

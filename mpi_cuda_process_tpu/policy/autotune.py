@@ -53,6 +53,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import plan
 from ..config import RunConfig
 from ..obs import ledger as ledger_lib
 
@@ -151,15 +152,6 @@ def tune_variant(family: str, n: int) -> KernelVariant:
 
 # ---------------------------------------------------------- validation
 
-def _stencil_for(cfg: RunConfig):
-    from ..ops import stencil as stencil_lib
-
-    params = dict(cfg.params)
-    if cfg.dtype:
-        params.setdefault("dtype", jnp.dtype(cfg.dtype))
-    return stencil_lib.make_stencil(cfg.stencil, **params)
-
-
 def _mesh_counts(cfg: RunConfig) -> Tuple[int, ...]:
     return (tuple(int(c) for c in cfg.mesh) + (1,) * 3)[:3]
 
@@ -173,7 +165,7 @@ def _config_reason(cfg: RunConfig, v: KernelVariant) -> Optional[str]:
         return ("kernel variants tune the temporal-blocking kernels: "
                 "needs an explicit --fuse K")
     if v.family == "tiled":
-        # the padded window kernel is unsharded-only (cli rejects
+        # the padded window kernel is unsharded-only (plan.build rejects
         # --fuse-kind tiled under --mesh): the opposite prerequisites
         # of the streaming families
         if cfg.fuse_kind != "tiled":
@@ -213,7 +205,7 @@ def validate_variant(v: KernelVariant, cfg: RunConfig,
         return False, reason
     if st is None:
         try:
-            st = _stencil_for(cfg)
+            st = plan.make_cfg_stencil(cfg)
         except Exception as e:  # unknown stencil: nothing to validate
             return False, f"no stencil to validate against: {e}"
     from ..ops.pallas.fused import _halo_per_micro, _sublane
@@ -459,9 +451,7 @@ def _probe_mcells(cfg: RunConfig, calls: int) -> float:
     """Short measured probe: Mcells/s of ``cfg`` over a scanned step
     window, warm-timed as t(4N) - t(N) so compile and ramp cost cancel
     (the ``measure.py`` discipline, miniaturized)."""
-    from .. import cli as cli_lib
-
-    _, step_fn, fields, _ = cli_lib.build(cfg)
+    _, step_fn, fields, _ = plan.build(cfg)
 
     def scan(fs, n):
         def body(c, _):
@@ -513,7 +503,7 @@ def maybe_autotune(cfg: RunConfig,
         raise ValueError(f"--autotune: {reason}")
     backend = backend or jax.default_backend()
     ledger_path = ledger_path or ledger_lib.default_ledger_path()
-    st = _stencil_for(cfg)
+    st = plan.make_cfg_stencil(cfg)
     todo = [""] + [i for i in sweep_ids(cfg, attribution)
                    if ids is None or i in ids]
     rows: List[Dict[str, Any]] = []

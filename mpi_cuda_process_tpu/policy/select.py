@@ -1,7 +1,7 @@
 """Rank candidate execution configs and resolve ``--auto-policy``.
 
 This is the runtime promotion of ``benchmarks/policy_advice.py``: the
-offline advisor reads a campaign results table and prints which cli
+offline advisor reads a campaign results table and prints which plan
 data-table edits the numbers support; :func:`resolve` reads the
 campaign ledger's ``best_known`` table directly and *makes* the call
 for one run, at launch or mid-flight.
@@ -79,12 +79,11 @@ import os
 from typing import Any, Dict, FrozenSet, Iterable, List, Optional, Set, Tuple
 
 import jax
-import jax.numpy as jnp
 
+from .. import plan
 from ..config import RunConfig
 from ..obs import costmodel
 from ..obs import ledger as ledger_lib
-from ..ops import stencil as stencil_lib
 
 log = logging.getLogger("mpi_cuda_process_tpu.policy")
 
@@ -135,10 +134,7 @@ def locked_fields(cfg: RunConfig) -> FrozenSet[str]:
 
 def _stencil_for(cfg: RunConfig):
     try:
-        params = dict(cfg.params)
-        if cfg.dtype:
-            params.setdefault("dtype", jnp.dtype(cfg.dtype))
-        return stencil_lib.make_stencil(cfg.stencil, **params)
+        return plan.make_cfg_stencil(cfg)
     except Exception as e:  # unknown stencil/params: predictions degrade
         log.debug("policy: no stencil for %s: %s", cfg.stencil, e)
         return None
@@ -172,35 +168,6 @@ def _grid_ok(grid: Tuple[int, ...], shape: Tuple[int, ...],
     than the slab the neighbor exchange needs."""
     return all(g % c == 0 and (c == 1 or g // c >= max(2 * halo, 2))
                for g, c in zip(grid, shape))
-
-
-def _fuse_k(cfg: RunConfig, backend: str) -> Optional[int]:
-    """The auto-fuse k a candidate may propose, mirroring
-    ``cli.maybe_auto_fuse``'s eligibility rules (measured winner
-    tables, cadence divisibility, no step-observing features)."""
-    if backend != "tpu" or cfg.compute != "auto":
-        return None
-    if (cfg.periodic or cfg.tol > 0 or cfg.debug_checks or cfg.ensemble
-            or cfg.resume):
-        return None
-    from .. import cli as _cli  # deferred: cli imports policy lazily too
-    if len(cfg.grid) == 2:
-        k = _cli._AUTO_FULL_K.get(cfg.stencil)
-    else:
-        dtype = cfg.dtype or dict(cfg.params).get("dtype")
-        if dtype is None or jnp.dtype(dtype) == jnp.float32:
-            k = _cli._AUTO_FUSE_K.get(cfg.stencil)
-        elif jnp.dtype(dtype) == jnp.bfloat16:
-            k = _cli._AUTO_FUSE_K_BF16.get(cfg.stencil)
-        else:
-            k = None
-    if not k:
-        return None
-    cadences = [cfg.iters, cfg.log_every, cfg.checkpoint_every,
-                cfg.check_finite, cfg.dump_every]
-    if any(v % k for v in cadences if v):
-        return None
-    return k
 
 
 def _apply(cfg: RunConfig, locked: FrozenSet[str],
@@ -275,7 +242,7 @@ def candidates(cfg: RunConfig, backend: str,
         {f: getattr(cfg, f) for f in MODE_FIELDS},  # requested, verbatim
         {},                                         # unsharded baseline
     ]
-    fuse_k = _fuse_k(cfg, backend)
+    fuse_k = plan.auto_fuse_k(cfg, backend)
     if fuse_k:
         modes_list.append({"fuse": fuse_k})
     if cfg.ensemble:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
+from .. import plan
 from ..config import RunConfig
 
 __all__ = ["AdmissionError", "AdmissionController"]
@@ -50,7 +51,6 @@ class AdmissionController:
         Returns ``{"total_bytes", "parts", "hbm_bytes"}``; pure host
         arithmetic — nothing compiles, nothing allocates.
         """
-        from ..cli import _make_cfg_stencil
         from ..utils import budget
 
         hbm = self.hbm_bytes
@@ -75,7 +75,7 @@ class AdmissionController:
                         for name, t, _ in details],
                     "worst_group": worst_name,
                     "hbm_bytes": int(hbm)}
-        st = _make_cfg_stencil(build_cfg)
+        st = plan.make_cfg_stencil(build_cfg)
         total, parts = budget.estimate_run_bytes(
             st, build_cfg.grid, mesh=build_cfg.mesh, fuse=build_cfg.fuse,
             ensemble=build_cfg.ensemble, periodic=build_cfg.periodic,

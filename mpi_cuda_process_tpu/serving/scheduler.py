@@ -66,7 +66,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-from .. import cancellation
+from .. import cancellation, plan
 from ..config import RunConfig
 from ..engine import RunHandle
 from .admission import AdmissionController, AdmissionError
@@ -191,15 +191,13 @@ class ResidentClass:
         """Compile the class step at ``capacity`` members (dummy ballast
         state: every occupied slot is overwritten with its job's own
         solo init before it computes anything a tenant sees)."""
-        from .. import cli
-
         from ..parallel import mesh as mesh_lib
 
         build_cfg = class_config(self.template, capacity)
         with self.engine._step_lock:
-            st, step_fn, fields, _ = cli.build(build_cfg)
+            st, step_fn, fields, _ = plan.build(build_cfg)
         mesh = mesh_lib.make_mesh(build_cfg.mesh) \
-            if cli._uses_mesh(build_cfg) else None
+            if plan.uses_mesh(build_cfg) else None
         with self.engine._cv:
             self._mesh = mesh
             self.st = st
@@ -235,12 +233,11 @@ class ResidentClass:
         compile — once per rung per class, priced by admission before
         it is attempted) or ``"shrink"`` (release the rung; freed
         capacity re-prices future admissions)."""
-        from .. import cli
         from ..parallel import reshard as reshard_lib
 
         build_cfg = class_config(self.template, capacity)
         with self.engine._step_lock:
-            _, step_fn, _, _ = cli.build(build_cfg)
+            _, step_fn, _, _ = plan.build(build_cfg)
         with self.engine._cv:
             occupied = [(i, j) for i, j in enumerate(self.slots)
                         if j is not None]
@@ -764,9 +761,7 @@ class ServingEngine:
         self.name = name
         self.per_job_telemetry = bool(per_job_telemetry)
         self.admission = AdmissionController(hbm_bytes=hbm_bytes)
-        from .. import cli
-
-        self.compile_cache = cli.enable_compile_cache(compile_cache)
+        self.compile_cache = plan.enable_compile_cache(compile_cache)
         self.telemetry_dir = telemetry_dir or \
             trace_lib.default_telemetry_dir()
         os.makedirs(self.telemetry_dir, exist_ok=True)

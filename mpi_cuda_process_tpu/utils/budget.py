@@ -73,7 +73,7 @@ def estimate_run_bytes(
 ) -> Tuple[int, List[Tuple[str, int]]]:
     """Peak per-device live bytes for a run, with a labeled breakdown.
 
-    Mirrors ``cli.build``'s strategy selection coarsely: temporal blocking
+    Mirrors ``plan.build``'s strategy selection coarsely: temporal blocking
     (``fuse``) on its padded / pad-free / sharded (exchange-padded,
     SMEM-origin frame) variants, the raw whole-step kernels (no
     transient: the state is its own halo), and the jnp pad -> update
@@ -137,7 +137,7 @@ def estimate_run_bytes(
     if exchange == "rdma" and not (sharded and fuse and len(local) == 3
                                    and fuse_kind == "stream"):
         # the estimate must describe the path the run actually takes:
-        # off the sharded streaming kind, cli/stepper raise before any
+        # off the sharded streaming kind, plan/stepper raise before any
         # allocation — never price a transport the run would refuse
         parts.append(("rdma exchange: UNSUPPORTED off the sharded "
                       "streaming kind (the run refuses before "
@@ -224,10 +224,11 @@ def estimate_run_bytes(
         if sharded and fuse_kind == "stream":
             # slab operands only (the VMEM rings are not HBM).  Probe
             # construction so a "fits" never describes an unconstructible
-            # run (cli raises before any allocation).  z-only meshes take
-            # the zslab contract; meshes that shard y take the 2-axis
-            # contract (y slabs + corners at natural width m, plus the
-            # call's wm_a-aligned copies of the y-facing operands).
+            # run (plan.build raises before any allocation).  z-only
+            # meshes take the zslab contract; meshes that shard y take
+            # the 2-axis contract (y slabs + corners at natural width m,
+            # plus the call's wm_a-aligned copies of the y-facing
+            # operands).
             from ..ops.pallas.fused import _sublane
             from ..ops.pallas.streamfused import (
                 build_stream_2axis_call,
@@ -282,8 +283,8 @@ def estimate_run_bytes(
                     parts.append(_pipeline_part(base_b))
         elif sharded and fuse_kind == "padfree":
             # forced pad-free under a mesh: no padded fallback exists
-            # (make_sharded_fused_step returns None and cli raises), so
-            # never estimate the padded transient
+            # (make_sharded_fused_step returns None and plan.build
+            # raises), so never estimate the padded transient
             part = _padfree_slab_part()
             if part is not None:
                 parts.append(part[:2])
@@ -328,7 +329,7 @@ def estimate_run_bytes(
             # sliding-window manual-DMA kernel: the ring lives in VMEM,
             # HBM holds only state + output.  Probe construction (pure
             # Python) so a "fits" never describes an unconstructible run;
-            # when unbuildable, cli.build refuses before any allocation.
+            # when unbuildable, plan.build refuses before any allocation.
             # The unsharded kernel is guard-frame only; --ensemble now
             # BATCHES it (round 15: an explicit leading batch grid
             # dimension — the old "unbatched only" wall is deleted), so
@@ -353,7 +354,7 @@ def estimate_run_bytes(
                          "(the run refuses before allocating)")
             parts.append((label, 0))
         elif fuse_kind == "padfree":
-            # forced pad-free: there is no padded fallback (cli.build
+            # forced pad-free: there is no padded fallback (plan.build
             # raises instead), so never estimate the padded transient
             ok = make_fused_step(stencil, grid, fuse, interpret=True,
                                  periodic=periodic, padfree=True) is not None
@@ -380,7 +381,7 @@ def estimate_run_bytes(
     elif compute == "raw":
         # whole-step raw kernels: the state is its own halo — no transient
         # (callers pass compute="raw" when the run will actually take that
-        # path; see cli._check_mem_budget)
+        # path; see plan.budget_args)
         parts.append(("raw whole-step kernel: no pad transient", 0))
     else:
         # jnp pad -> update -> re-pin: one padded copy per halo'd field

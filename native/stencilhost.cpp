@@ -114,11 +114,13 @@ class WriterPool {
     cv_.notify_one();
   }
 
-  // Block until all submitted jobs completed; returns error count since start.
+  // Block until all submitted jobs completed; returns the count of writes
+  // that failed since the previous wait_all (a failure is reported once,
+  // not to every later caller in the process).
   int64_t wait_all() {
     std::unique_lock<std::mutex> lk(mu_);
     done_cv_.wait(lk, [this] { return pending_ == 0; });
-    return errors_.load();
+    return errors_.exchange(0);
   }
 
   int64_t pending() {

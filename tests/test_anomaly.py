@@ -606,9 +606,10 @@ def test_anomaly_jaxpr_invariance_on_vs_off(tmp_path):
 
 
 def test_engine_handle_surfaces_anomalies(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(cli.config_from_args(_CLEAN_ARGS))
     h.result(timeout=120)
     assert h.anomalies() == []  # clean run: the doctor stays silent

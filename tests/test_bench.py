@@ -139,7 +139,7 @@ def test_bench_refuses_to_measure_off_tpu(bench, capsys):
 def test_mktable_regenerates_from_campaign(tmp_path):
     """benchmarks/mktable.py renders the measured table from a results
     file with the LIVE auto-policy picks bolded — the mechanism that
-    keeps BASELINE.md and cli.py from silently disagreeing."""
+    keeps BASELINE.md and plan.py from silently disagreeing."""
     import subprocess
     import sys as _sys
 

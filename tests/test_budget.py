@@ -106,7 +106,8 @@ def test_small_config_passes_guard():
 
 
 def test_cli_flag_parses_and_cpu_backend_skips():
-    from mpi_cuda_process_tpu.cli import _check_mem_budget, config_from_args
+    from mpi_cuda_process_tpu.cli import config_from_args
+    from mpi_cuda_process_tpu.plan import check_mem_budget
 
     cfg = config_from_args(
         ["--stencil", "wave3d", "--grid", "4096,4096,4096",
@@ -114,7 +115,7 @@ def test_cli_flag_parses_and_cpu_backend_skips():
     assert cfg.mem_check == "error"
     # CPU backend: the guard is a no-op (virtual-device test meshes would
     # otherwise trip on host-RAM-sized grids)
-    _check_mem_budget(cfg)
+    check_mem_budget(cfg)
 
 
 def test_raw_path_has_no_transient():

@@ -498,28 +498,29 @@ def test_dump_every_writes_snapshots(tmp_path):
 
 def test_auto_fuse_policy_table(monkeypatch):
     """maybe_auto_fuse upgrades exactly the measured fused winners on TPU."""
-    from mpi_cuda_process_tpu import cli
+    from mpi_cuda_process_tpu import plan
     from mpi_cuda_process_tpu.ops.pallas import fused
 
     # Patching the shared jax module makes _interpret_default() think it is
     # on TPU too — pin interpret mode explicitly (in fused's namespace,
     # where the name is bound) so the tileability probe never constructs a
     # real TPU pallas_call on the CPU test backend.
-    monkeypatch.setattr(cli.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fused, "_interpret_default", lambda: True)
     base = dict(grid=(16, 16, 128), iters=8)
     # winners upgrade (the builder still validates tileability)
     for name in ("heat3d", "heat3d27", "wave3d"):
-        assert cli.maybe_auto_fuse(RunConfig(stencil=name, **base)).fuse == 4
+        assert plan.maybe_auto_fuse(RunConfig(stencil=name, **base)).fuse == 4
     # non-winners and explicit modes never upgrade
-    assert cli.maybe_auto_fuse(RunConfig(stencil="advect3d", **base)).fuse == 0
-    assert cli.maybe_auto_fuse(
+    assert plan.maybe_auto_fuse(
+        RunConfig(stencil="advect3d", **base)).fuse == 0
+    assert plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", compute="jnp", **base)).fuse == 0
     # bf16 gated until the k=8 win is measured on the real chip
-    assert cli.maybe_auto_fuse(
+    assert plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", dtype="bfloat16", **base)).fuse == 0
     # cadence misalignment blocks the upgrade
-    assert cli.maybe_auto_fuse(
+    assert plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", grid=(16, 16, 128), iters=6)).fuse == 0
 
 
@@ -527,30 +528,30 @@ def test_auto_fuse_kind_table(monkeypatch):
     """A family flipped into _AUTO_FUSE_KIND routes its auto upgrade
     through the streaming kernel — probing the EXACT kernel build() will
     construct, with a tiled fallback when stream declines the shape."""
-    from mpi_cuda_process_tpu import cli
+    from mpi_cuda_process_tpu import plan
     from mpi_cuda_process_tpu.ops.pallas import fused, streamfused
 
-    monkeypatch.setattr(cli.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fused, "_interpret_default", lambda: True)
     monkeypatch.setattr(streamfused, "_interpret_default", lambda: True)
-    monkeypatch.setattr(cli, "_AUTO_FUSE_KIND", {"heat3d": "stream"})
+    monkeypatch.setattr(plan, "_AUTO_FUSE_KIND", {"heat3d": "stream"})
     # streamable shape: upgrade carries the kind
-    got = cli.maybe_auto_fuse(
+    got = plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", grid=(24, 32, 128), iters=8))
     assert (got.fuse, got.fuse_kind) == (4, "stream")
     # stream-untileable shape (two z chunks): falls back to the tiled
     # upgrade instead of hard-erroring in build()
-    got = cli.maybe_auto_fuse(
+    got = plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", grid=(16, 16, 128), iters=8))
     assert (got.fuse, got.fuse_kind) == (4, "auto")
     # empty table (the shipped default): kind never set by auto
-    monkeypatch.setattr(cli, "_AUTO_FUSE_KIND", {})
-    got = cli.maybe_auto_fuse(
+    monkeypatch.setattr(plan, "_AUTO_FUSE_KIND", {})
+    got = plan.maybe_auto_fuse(
         RunConfig(stencil="heat3d", grid=(24, 32, 128), iters=8))
     assert (got.fuse, got.fuse_kind) == (4, "auto")
     # a user-forced kind WITHOUT --fuse is never auto-upgraded: it must
     # reach build()'s "--fuse-kind requires an explicit --fuse K" guard
-    got = cli.maybe_auto_fuse(RunConfig(
+    got = plan.maybe_auto_fuse(RunConfig(
         stencil="heat3d", grid=(24, 32, 128), iters=8,
         fuse_kind="stream"))
     assert (got.fuse, got.fuse_kind) == (0, "stream")
@@ -621,22 +622,22 @@ def test_explicit_fuse_over_fullgrid_vmem_raises_by_name():
 def test_auto_full_2d_policy_table(monkeypatch):
     """2D families upgrade via _AUTO_FULL_K (whole-grid VMEM kernel) once
     a family is flipped in; the table ships empty until measured."""
-    from mpi_cuda_process_tpu import cli
+    from mpi_cuda_process_tpu import plan
     from mpi_cuda_process_tpu.ops.pallas import fullgrid
 
-    monkeypatch.setattr(cli.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fullgrid, "_interpret_default", lambda: True)
     base = dict(grid=(64, 128), iters=16)
     # empty table: no upgrade
-    assert cli.maybe_auto_fuse(RunConfig(stencil="life", **base)).fuse == 0
+    assert plan.maybe_auto_fuse(RunConfig(stencil="life", **base)).fuse == 0
     # flipped family upgrades (builder still validates alignment/VMEM)
-    monkeypatch.setitem(cli._AUTO_FULL_K, "life", 8)
-    assert cli.maybe_auto_fuse(RunConfig(stencil="life", **base)).fuse == 8
+    monkeypatch.setitem(plan._AUTO_FULL_K, "life", 8)
+    assert plan.maybe_auto_fuse(RunConfig(stencil="life", **base)).fuse == 8
     # cadence misalignment still blocks
-    assert cli.maybe_auto_fuse(
+    assert plan.maybe_auto_fuse(
         RunConfig(stencil="life", grid=(64, 128), iters=12)).fuse == 0
     # unaligned width declines at the builder
-    assert cli.maybe_auto_fuse(
+    assert plan.maybe_auto_fuse(
         RunConfig(stencil="life", grid=(64, 100), iters=16)).fuse == 0
 
 
@@ -687,10 +688,10 @@ def test_auto_fuse_at_1024_probes_padfree_variant(monkeypatch):
     """At 1024^3 the auto-fuse probe must construct the PAD-FREE kernel
     (the padded transient is the measured RESOURCE_EXHAUSTED) — pin that
     maybe_auto_fuse upgrades, i.e. the probe chain doesn't decline."""
-    from mpi_cuda_process_tpu import cli
+    from mpi_cuda_process_tpu import plan
     from mpi_cuda_process_tpu.ops.pallas import fused
 
-    monkeypatch.setattr(cli.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fused, "_interpret_default", lambda: True)
     built = {}
     orig = fused.make_fused_step
@@ -700,10 +701,10 @@ def test_auto_fuse_at_1024_probes_padfree_variant(monkeypatch):
         return orig(st, grid, k, **kw)
 
     monkeypatch.setattr(fused, "make_fused_step", spy)
-    # cli imported make_fused_step by name inside the function: patch the
+    # plan imports make_fused_step by name inside the function: patch the
     # module it resolves from (it does a local import of fused each call)
     cfg = RunConfig(stencil="heat3d", grid=(1024, 1024, 1024), iters=8)
-    out = cli.maybe_auto_fuse(cfg)
+    out = plan.maybe_auto_fuse(cfg)
     assert out.fuse == 4
     assert built.get("padfree") is True  # the 1024^3 path, not the padded
 
@@ -714,17 +715,17 @@ def test_auto_fuse_declines_what_the_budget_refuses(monkeypatch, caplog):
     raw kernel) and says why in one line; heat3d 1024^3 still fuses."""
     import logging
 
-    from mpi_cuda_process_tpu import cli
+    from mpi_cuda_process_tpu import plan
     from mpi_cuda_process_tpu.ops.pallas import fused
 
-    monkeypatch.setattr(cli.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fused, "_interpret_default", lambda: True)
     base = dict(grid=(1024, 1024, 1024), iters=64, log_every=16)
     with caplog.at_level(logging.INFO, logger="mpi_cuda_process_tpu"):
-        out = cli.maybe_auto_fuse(RunConfig(stencil="wave3d", **base))
+        out = plan.maybe_auto_fuse(RunConfig(stencil="wave3d", **base))
     assert out.fuse == 0
     said = [r.getMessage() for r in caplog.records if "declined" in
             r.getMessage()]
     assert len(said) == 1 and "17.60 GiB" in said[0], said
-    assert cli.maybe_auto_fuse(RunConfig(stencil="heat3d", **base)).fuse \
+    assert plan.maybe_auto_fuse(RunConfig(stencil="heat3d", **base)).fuse \
         == 4

@@ -1,4 +1,4 @@
-"""Placement of jax's persistent compilation cache (cli.enable_compile_cache).
+"""Placement of jax's persistent compilation cache (enable_compile_cache).
 
 ``JAX_COMPILATION_CACHE_DIR`` wins and nothing in code sets another
 directory; otherwise ``--compile-cache DIR``; otherwise the fixed
@@ -13,6 +13,7 @@ import jax
 import pytest
 
 from mpi_cuda_process_tpu import cli, serving
+from mpi_cuda_process_tpu.plan import DEFAULT_COMPILE_CACHE
 
 
 @pytest.fixture
@@ -70,7 +71,7 @@ def test_env_dir_wins_over_flag_for_cli_and_engine(cache_on, tmp_path):
 
 def test_default_is_the_fixed_checkout_dir(cache_on):
     # placement only: nothing compiles while the default is in force
-    assert cli.enable_compile_cache(None) == cli.DEFAULT_COMPILE_CACHE
-    assert jax.config.jax_compilation_cache_dir == cli.DEFAULT_COMPILE_CACHE
+    assert cli.enable_compile_cache(None) == DEFAULT_COMPILE_CACHE
+    assert jax.config.jax_compilation_cache_dir == DEFAULT_COMPILE_CACHE
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    assert cli.DEFAULT_COMPILE_CACHE == os.path.join(repo, ".jax_cache")
+    assert DEFAULT_COMPILE_CACHE == os.path.join(repo, ".jax_cache")

@@ -584,9 +584,10 @@ def test_config_partition_is_total_and_disjoint():
 
 
 def test_engine_submit_handle_streams_member_telemetry(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat3d", grid=(16, 16, 64),
                              iters=8, ensemble=2, mesh=(2, 1, 1),
                              log_every=2))
@@ -612,18 +613,20 @@ def test_engine_submit_handle_streams_member_telemetry(tmp_path):
 
 
 def test_engine_rejects_supervised_requests(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     with pytest.raises(ValueError, match="supervise"):
         eng.submit(RunConfig(stencil="life", grid=(16, 16), iters=2,
                              supervise=True))
 
 
 def test_engine_delivers_run_errors(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat3d", grid=(96, 32, 128),
                              iters=8, fuse=4, fuse_kind="stream",
                              periodic=True))

@@ -492,9 +492,10 @@ def test_hetero_demo_cli_end_to_end(tmp_path):
 def test_hetero_demo_engine_submit(tmp_path):
     """The same coupled config through engine.submit: admitted,
     executed on the cli.run path, per-group stream on the handle."""
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     cfg = RunConfig(stencil="wave3d", grid=HET_GRID, iters=4,
                     groups=HET_GROUPS, health=True, log_every=2)
     handle = eng.submit(cfg)

@@ -521,10 +521,11 @@ def test_aggregate_worst_verdict_includes_diverged(tmp_path):
 def test_engine_handle_surfaces_health_verdict(tmp_path, monkeypatch):
     """ROADMAP item-1 contract: a scheduler evicts diverged members
     from handle.status() alone — no log parsing."""
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
     monkeypatch.setenv("FAULT_INJECT", "numerics:step=4:nan")
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(cli.config_from_args(
         ["--stencil", "heat2d", "--grid", "16,64", "--iters", "8",
          "--log-every", "2", "--health"]))

@@ -36,6 +36,19 @@ def test_async_write_failure_surfaces():
         native.wait_all()
 
 
+def test_write_failure_is_reported_once(tmp_path):
+    """A failed write raises at the next wait only: later writes in the
+    same process (checkpoints after a failed dump) still succeed."""
+    native.async_write_npy("/nonexistent_dir_xyz/g.npy",
+                           np.zeros(3, np.float32))
+    with pytest.raises(IOError):
+        native.wait_all()
+    native.async_write_npy(str(tmp_path / "ok.npy"), np.ones(3, np.float32))
+    native.wait_all()
+    np.testing.assert_array_equal(np.load(tmp_path / "ok.npy"),
+                                  np.ones(3, np.float32))
+
+
 def test_life_differential_native_vs_jax():
     """Three independent implementations agree: C++, numpy golden, JAX."""
     rng = np.random.default_rng(5)

@@ -426,9 +426,10 @@ def test_serve_aggregate_status_json_per_host(tmp_path):
 # ------------------------------------------------- engine request path
 
 def test_engine_request_span_ttfc_and_latency_histograms(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat2d", grid=(32, 128), iters=8,
                              log_every=2))
     h.result(timeout=120)
@@ -471,9 +472,10 @@ def test_engine_request_span_ttfc_and_latency_histograms(tmp_path):
 
 
 def test_engine_failed_request_still_accounted(tmp_path):
+    from mpi_cuda_process_tpu import cli
     from mpi_cuda_process_tpu.engine import SimulationEngine
 
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat2d", grid=(32, 128), iters=8,
                              log_every=2, fuse=3))  # 8 % 3 != 0: raises
     with pytest.raises(ValueError):

@@ -117,7 +117,13 @@ def test_measured_beats_predicted(tmp_path):
     assert d.overrides == {}
 
 
-def test_predicted_fallback_on_empty_ledger(tmp_path):
+def test_predicted_fallback_on_empty_ledger(tmp_path, monkeypatch):
+    # the roofline exists only for a device kind costmodel knows: rank
+    # as on a v5e (on the CPU backend there is no prediction to fall to)
+    from mpi_cuda_process_tpu.obs import costmodel
+
+    monkeypatch.setattr(costmodel, "device_peaks", lambda kind=None:
+                        costmodel.DEVICE_PEAKS["TPU v5 lite"])
     led = str(tmp_path / "none.jsonl")
     d = ps.resolve(_cfg(), backend="cpu", ledger_path=led)
     assert d.provenance == "predicted"

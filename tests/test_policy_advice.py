@@ -1,6 +1,6 @@
 """benchmarks/policy_advice.py: the campaign-results -> policy-flip
 advisor.  A wrong recommendation here costs a wrong one-line edit in
-cli.py's auto tables at the end-of-round crunch, so each decision branch
+plan.py's auto tables at the end-of-round crunch, so each decision branch
 is pinned against synthetic results with known winners.  Pure file
 reading — no backend, no kernels."""
 

@@ -231,7 +231,7 @@ def test_router_events_fold_into_status(tmp_path):
 def test_cancelled_requests_excluded_from_latency_histograms(tmp_path):
     """Pin 5 (engine level): cancel rides its own counter; the
     ttfc/latency histograms only ever see non-cancelled requests."""
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     done = eng.submit(_cfg(seed=1, iters=4))
     done.result(timeout=300)
     with eng.metrics.lock:

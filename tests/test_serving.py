@@ -289,7 +289,7 @@ def test_engine_cancel_is_third_outcome_everywhere(tmp_path):
     """RunHandle.cancel through the PR-10 engine: phase 'cancelled',
     a ``cancelled`` event (never ``error``), verdict CANCELLED on
     /status.json, quarantined 'cancelled' in the ledger."""
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat2d", grid=(64, 64),
                              iters=262144, log_every=64))
     deadline = time.time() + 120
@@ -316,7 +316,7 @@ def test_engine_cancel_is_third_outcome_everywhere(tmp_path):
 
 
 def test_cancel_after_done_returns_false(tmp_path):
-    eng = SimulationEngine(telemetry_dir=str(tmp_path))
+    eng = SimulationEngine(cli.run, telemetry_dir=str(tmp_path))
     h = eng.submit(RunConfig(stencil="heat2d", grid=(16, 16), iters=4))
     h.result(timeout=120)
     assert h.cancel() is False
