@@ -214,8 +214,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="execution strategy (auto: the measured-fastest "
                         "path per stencil/size — temporal-blocking or raw "
                         "whole-step Pallas kernels where they beat XLA's "
-                        "fusion, jnp elsewhere; falls back to jnp if a "
-                        "kernel fails, never crashes a valid config)")
+                        "fusion, jnp elsewhere; an auto-selected kernel "
+                        "that fails raises its own error)")
     p.add_argument("--check-finite", type=int, default=0,
                    help="every N steps, verify all fields are finite and "
                         "abort with the failing step range if not (debug "

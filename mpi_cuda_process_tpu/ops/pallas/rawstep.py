@@ -366,3 +366,96 @@ def make_raw_step(
         return (call(u, u, u),)
 
     return step
+
+
+# ---------------------------------------------------------------------------
+# the sharded heat3d pass: a z/y-sharded block, its exchanged faces as
+# operands
+# ---------------------------------------------------------------------------
+
+
+def _sharded_heat_kernel(alpha, bz, nzb, gshape, interpret, origins,
+                         prev_p, cur, next_p, zlo, zhi, ylo, yhi, out):
+    # The chunks at the block's z faces take their outer plane from the
+    # exchanged face, not from the clamped own-block plane
+    i = jnp.full(zlo.shape, pl.program_id(0), jnp.int32)
+    prev = jnp.where(i == 0, zlo[...], prev_p[...])
+    nxt = jnp.where(i == nzb - 1, zhi[...], next_p[...])
+    s = jnp.concatenate([prev, cur[...], nxt], axis=0)
+    u = s[1:bz + 1]
+    yi = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    # y taps: rolls, whose wrapped rows 0 and Y-1 take the exchanged faces
+    y_minus = jnp.where(yi == 0, ylo[...], _roll(u, 1, 1, interpret))
+    y_plus = jnp.where(yi == u.shape[1] - 1, yhi[...],
+                       _roll(u, -1, 1, interpret))
+    # _slab_lap7's sum, in its order: bit-identical to the jnp sharded step
+    lap = (s[0:bz] + s[2:bz + 2] + y_minus + y_plus
+           + _roll(u, 1, 2, interpret) + _roll(u, -1, 2, interpret)
+           - 6.0 * u)
+    new = u + alpha * lap
+    Z, Y, X = gshape
+    zi = jax.lax.broadcasted_iota(jnp.int32, u.shape, 0) \
+        + origins[0] + pl.program_id(0) * bz
+    yi = yi + origins[1]
+    xi = jax.lax.broadcasted_iota(jnp.int32, u.shape, 2)
+    frame = ((zi < 1) | (zi >= Z - 1) | (yi < 1) | (yi >= Y - 1)
+             | (xi < 1) | (xi >= X - 1))
+    out[...] = jnp.where(frame, u, new)
+
+
+def build_sharded_raw_call(
+    stencil: Stencil,
+    local_shape: Sequence[int],
+    global_shape: Sequence[int],
+    interpret: Optional[bool] = None,
+):
+    """One guard-frame step of a z/y-sharded block as one Pallas pass.
+
+    The sharded twin of ``make_raw_step``'s heat3d path: the unpadded
+    local block is read in z-chunks, and the exchanged faces arrive as
+    separate operands, never concatenated onto the block.  The call
+    takes origins (int32 (2,): the block's global z and y offsets), the
+    block three times (chunk and the two clamped halo-plane views), the
+    z faces (1, Ly, X) before and after the block, and the y faces
+    (Lz, 1, X) before and after it; it returns the advanced block.  The
+    z faces have constant block indices, so each is fetched once per
+    call.  The y taps stay rolls, and rows 0 and Ly-1 take the y faces
+    in place of the wrapped values; the x axis is whole, and its wrapped
+    taps land on frame cells, which the mask re-pins.  Bit-identical to
+    ``parallel.stepper.make_sharded_step``'s update.
+
+    Returns None for a stencil other than heat3d (the one single-field
+    face-tap stencil with halo 1 this pass carries; heat3d27 reads edges
+    and corners), a dtype other than float32 (Mosaic rotates 32-bit data
+    only), a sharded x axis, or a block the z-chunking cannot tile.
+    """
+    if stencil.name != "heat3d" or len(global_shape) != 3 \
+            or jnp.dtype(stencil.dtype) != jnp.float32:
+        return None
+    if interpret is None:
+        interpret = _interpret_default()
+    Lz, Ly, X = (int(s) for s in local_shape)
+    gshape = tuple(int(g) for g in global_shape)
+    if X != gshape[2]:
+        return None
+    _, halo, live = _TAPS[stencil.name]
+    bz = _pick_bz(Lz, Ly * X * jnp.dtype(stencil.dtype).itemsize, halo,
+                  live)
+    if bz == 0:
+        return None
+    prev_p, cur, next_p = _zspecs(Lz, Ly, X, bz, halo)
+    zface = pl.BlockSpec((1, Ly, X), lambda i: (0, 0, 0))
+    yface = pl.BlockSpec((bz, 1, X), lambda i: (i, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_sharded_heat_kernel,
+                          float(stencil.params["alpha"]), bz, Lz // bz,
+                          gshape, interpret),
+        name="rawstep_sharded",
+        grid=(Lz // bz,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), prev_p, cur,
+                  next_p, zface, zface, yface, yface],
+        out_specs=pl.BlockSpec((bz, Ly, X), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Lz, Ly, X), stencil.dtype),
+        interpret=interpret,
+        compiler_params=None if interpret else _COMPILER_PARAMS,
+    )

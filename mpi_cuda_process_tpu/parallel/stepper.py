@@ -339,6 +339,62 @@ def make_sharded_step(
     return step
 
 
+def make_sharded_raw_step(
+    stencil: Stencil,
+    mesh: Mesh,
+    global_shape: Sequence[int],
+    periodic: bool = False,
+    ensemble: int = 0,
+    interpret: Optional[bool] = None,
+):
+    """:func:`make_sharded_step` as one Pallas pass over the unpadded
+    shard (``rawstep.build_sharded_raw_call``), or None.
+
+    The faces come from the width-1 slab exchange of each sharded axis
+    (``halo.exchange_slabs_axis``; a bc fill on an unsharded one) and go
+    to the kernel as operands: nothing is padded, concatenated or copied.
+    The 7-point stencil needs no corners.  Bit-identical to
+    :func:`make_sharded_step` with its defaults.  The pass writes out of
+    place (later chunks read planes an in-place write would have
+    overwritten), so the step declares a carry period of 2: the runner
+    ping-pongs between the donated state and one temporary.
+
+    Declines (None) periodic and ensemble runs and whatever the kernel
+    builder declines (stencils other than heat3d, dtypes other than f32,
+    an x-sharded mesh, a shard the z-chunking cannot tile).
+    """
+    from ..ops.pallas.rawstep import build_sharded_raw_call
+    from .halo import exchange_slabs_axis
+
+    if periodic or ensemble or stencil.ndim != 3:
+        return None
+    axis_names, counts = _resolve_mesh_axes(3, mesh)
+    if any(g % c for g, c in zip(global_shape, counts)):
+        return None  # make_sharded_step raises the named error
+    local_shape = tuple(g // c for g, c in zip(global_shape, counts))
+    call = build_sharded_raw_call(stencil, local_shape, global_shape,
+                                  interpret=interpret)
+    if call is None:
+        return None
+    (bc,) = stencil.bc_value
+
+    def local_step(fields: Fields) -> Fields:
+        (u,) = fields
+        zlo, zhi = exchange_slabs_axis(u, 0, axis_names[0], counts[0], 1, bc)
+        ylo, yhi = exchange_slabs_axis(u, 1, axis_names[1], counts[1], 1, bc)
+        origins = jnp.array([
+            lax.axis_index(axis_names[d]) * local_shape[d]
+            if axis_names[d] else 0
+            for d in (0, 1)], dtype=jnp.int32)
+        return (call(origins, u, u, u, zlo, zhi, ylo, yhi),)
+
+    step = _member_shard_map(local_step, mesh, 3, 0)
+    step._raw_kind = "sharded"
+    step._carry_period = 2
+    step._out_of_place = True
+    return step
+
+
 def make_sharded_fused_step(
     stencil: Stencil,
     mesh: Mesh,

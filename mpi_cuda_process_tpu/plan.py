@@ -55,6 +55,10 @@ _RAW_WINS = {"heat3d27", "wave3d", "grayscott3d"}
 #   pass of _AUTO_FUSE_K instead)
 _RAW_ABOVE_CLIFF = {"heat3d", "heat3d4th"}
 _CLIFF_CELLS = 100_000_000
+# An unfused mesh run takes the sharded raw pass (rawstep.
+# build_sharded_raw_call) at every size wherever it builds: bit-identical
+# to the jnp sharded step, with no pad and no whole-shard copies.
+#   heat3d f32: heat3d-1024-2x2.log8
 
 # Temporal blocking (ops/pallas/fused.py), k steps per HBM pass, f32;
 # taken when step accounting and the HBM budget allow (maybe_auto_fuse).
@@ -229,12 +233,14 @@ def _probe_fuse(cfg: RunConfig, k: int) -> RunConfig:
 def budget_args(cfg: RunConfig, st) -> dict:
     """``budget.estimate_run_bytes``'s keyword arguments for ``cfg``.
 
-    The raw whole-step kernels carry no pad transient; the estimator is
-    told when the run will actually take that path (the kernel
-    constructor only constructs — no compile happens here).
+    The raw whole-step kernels carry no pad transient (the sharded one
+    only its exchanged faces); the estimator is told when the run will
+    actually take that path (the kernel constructor only constructs — no
+    compile happens here).
     """
     compute = cfg.compute
-    if not cfg.fuse and resolve_raw_step(cfg, st) is not None:
+    if not cfg.fuse and (resolve_raw_step(cfg, st) is not None
+                         or _sharded_raw_builds(cfg, st)):
         compute = "raw"
     return dict(mesh=cfg.mesh, fuse=cfg.fuse, ensemble=cfg.ensemble,
                 periodic=cfg.periodic, compute=compute,
@@ -261,7 +267,8 @@ def _fused_fits(cfg: RunConfig, st) -> bool:
 
 
 def _raw_eligible(cfg: RunConfig, name: str) -> bool:
-    """Structural eligibility of the whole-step raw Pallas kernel."""
+    """Structural eligibility of the unsharded whole-step raw Pallas
+    kernel (a mesh run's is :func:`_sharded_raw_eligible`)."""
     if cfg.periodic or cfg.ensemble or uses_mesh(cfg) or cfg.fuse:
         return False
     if cfg.compute == "jnp" or jax.default_backend() != "tpu":
@@ -285,6 +292,30 @@ def resolve_raw_step(cfg: RunConfig, st):
     if not rawstep.raw_step_supported(st):
         return None
     return rawstep.make_raw_step(st, cfg.grid)
+
+
+def _sharded_raw_eligible(cfg: RunConfig) -> bool:
+    """Whether a mesh run may take the sharded whole-step raw kernel: an
+    unfused ``--compute auto`` run on a TPU, guard-frame, unbatched, with
+    the plain ppermute exchange and no overlap split.  The kernel builder
+    decides the rest (stencil, dtype, an unsharded x axis, tiling)."""
+    return (uses_mesh(cfg) and cfg.compute == "auto" and not cfg.fuse
+            and jax.default_backend() == "tpu"
+            and not (cfg.periodic or cfg.ensemble or cfg.overlap
+                     or cfg.pipeline)
+            and cfg.exchange == "ppermute")
+
+
+def _sharded_raw_builds(cfg: RunConfig, st) -> bool:
+    """Whether :func:`_build` will take the sharded whole-step raw kernel
+    for ``cfg``, without a mesh (the kernel constructor only constructs)."""
+    from .ops.pallas.rawstep import build_sharded_raw_call
+
+    if not _sharded_raw_eligible(cfg) or len(cfg.mesh) != len(cfg.grid) \
+            or any(g % c for g, c in zip(cfg.grid, cfg.mesh)):
+        return False
+    local = tuple(g // c for g, c in zip(cfg.grid, cfg.mesh))
+    return build_sharded_raw_call(st, local, cfg.grid) is not None
 
 
 def resolve_compute_fn(cfg: RunConfig, st):
@@ -585,9 +616,16 @@ def _build(cfg: RunConfig):
             fields, start_step = _resume(cfg, fields)
         return st, step_fn, fields, start_step
     if use_mesh:
-        step_fn = stepper_lib.make_sharded_step(
-            st, m, cfg.grid, periodic=cfg.periodic, compute_fn=compute_fn,
-            overlap=cfg.overlap, ensemble=cfg.ensemble)
+        step_fn = stepper_lib.make_sharded_raw_step(st, m, cfg.grid) \
+            if _sharded_raw_eligible(cfg) else None
+        if step_fn is not None:
+            log.info("compute: sharded whole-step raw Pallas kernel (%s)",
+                     st.name)
+        else:
+            step_fn = stepper_lib.make_sharded_step(
+                st, m, cfg.grid, periodic=cfg.periodic,
+                compute_fn=compute_fn, overlap=cfg.overlap,
+                ensemble=cfg.ensemble)
     elif raw_step is not None:
         log.info("compute: whole-step raw Pallas kernel (%s)", st.name)
         step_fn = raw_step

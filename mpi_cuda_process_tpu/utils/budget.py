@@ -76,8 +76,9 @@ def estimate_run_bytes(
     Mirrors ``plan.build``'s strategy selection coarsely: temporal blocking
     (``fuse``) on its padded / pad-free / sharded (exchange-padded,
     SMEM-origin frame) variants, the raw whole-step kernels (no
-    transient: the state is its own halo), and the jnp pad -> update
-    path.  Returns ``(total, [(label, bytes), ...])``.
+    transient: the state is its own halo; under a mesh, the exchanged
+    faces), and the jnp pad -> update path.  Returns
+    ``(total, [(label, bytes), ...])``.
 
     ``ensemble=N`` prices the batched run (round 15 — the UNBUILDABLE
     ensemble wall is gone: every kind that builds unbatched builds
@@ -378,6 +379,14 @@ def estimate_run_bytes(
         padded_b = batch * (ly + 2 * m) * lx * itemsize
         parts.append((f"2D fullgrid pad transient (+{2 * m} rows)",
                       nfields * padded_b))
+    elif compute == "raw" and sharded and len(local) == 3:
+        # the sharded raw pass (rawstep.build_sharded_raw_call): the
+        # exchanged z planes and y rows are its only transient
+        lz, ly, lx = local
+        parts.append(
+            ("sharded raw whole-step kernel: exchanged faces only "
+             "(2 z planes + 2 y rows), no pad transient",
+             batch * 2 * (ly + lz) * lx * itemsize * nfields))
     elif compute == "raw":
         # whole-step raw kernels: the state is its own halo — no transient
         # (callers pass compute="raw" when the run will actually take that

@@ -425,3 +425,19 @@ def test_inplace_leapfrog_pass_priced_as_the_state():
     gs = make_stencil("grayscott3d")
     t_gs, _ = budget.estimate_run_bytes(gs, (512,) * 3, compute="raw")
     assert t_gs == int(4 * 512**3 * 4 * 1.10)
+
+
+def test_sharded_raw_pass_priced_per_shard():
+    """The sharded raw pass on the 2x2 cell's 512x512x1024 shard: the
+    shard, one shard-sized ping-pong temporary and the four exchanged
+    faces, +10%; no pad transient."""
+    st = make_stencil("heat3d")
+    grid, mesh = (1024,) * 3, (2, 2, 1)
+    shard = 512 * 512 * 1024 * 4
+    faces = 2 * (512 + 512) * 1024 * 4
+    total, parts = budget.estimate_run_bytes(st, grid, mesh=mesh,
+                                             compute="raw")
+    assert total == int((2 * shard + faces) * 1.10)
+    assert ("sharded raw whole-step kernel: exchanged faces only "
+            "(2 z planes + 2 y rows), no pad transient", faces) in parts
+    assert total < budget.estimate_run_bytes(st, grid, mesh=mesh)[0]

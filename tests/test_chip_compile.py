@@ -204,30 +204,49 @@ def test_stream_kernel_lowers(one_chip):
     _check(_compile(step, (_fields(st, grid, one_chip),)))
 
 
-@pytest.mark.parametrize("fused", [False, True], ids=["jnp", "fused4"])
-def test_sharded_heat3d_2x2(topo, fused):
+@pytest.mark.parametrize("kind", ["jnp", "fused4", "raw"])
+def test_sharded_heat3d_2x2(topo, kind):
     """BASELINE config 3 on a 2x2 mesh of described chips: one
-    512x512x1024 shard per chip, halos by collective-permute."""
+    512x512x1024 shard per chip, halos by collective-permute.  The raw
+    case is the runner of the 2x2 cell, 8 sharded raw passes: two a scan
+    iteration, ping-ponged between the donated shard and one temporary,
+    so no shard-sized copy and at most one shard (plus the faces) of
+    temporaries."""
     from mpi_cuda_process_tpu.parallel.mesh import make_mesh
     from mpi_cuda_process_tpu.parallel.stepper import (
-        grid_partition_spec, make_sharded_fused_step, make_sharded_step,
+        grid_partition_spec, make_sharded_fused_step, make_sharded_raw_step,
+        make_sharded_step,
     )
 
     st = make_stencil("heat3d")
     grid = (1024, 1024, 1024)
+    shard = 512 * 512 * 1024 * jnp.dtype(st.dtype).itemsize
     mesh = make_mesh((2, 2, 1), devices=topo.devices)
-    if fused:
-        step = make_sharded_fused_step(st, mesh, grid, k=4,
-                                       interpret=False)
-        assert step is not None
-    else:
-        step = make_sharded_step(st, mesh, grid)
     sharding = NamedSharding(mesh, grid_partition_spec(3, mesh))
-    run = make_runner(step, 4, jit=False)
-    compiled = jax.jit(run, donate_argnums=0).lower(
-        _fields(st, grid, sharding)).compile()
-    assert "collective-permute" in compiled.as_text()
-    _check(compiled, "tpu_custom_call" if fused else "collective-permute")
+    if kind == "raw":
+        step = make_sharded_raw_step(st, mesh, grid, interpret=False)
+        assert step is not None
+        compiled = make_runner(step, 8).lower(
+            _fields(st, grid, sharding)).compile()
+    else:
+        if kind == "fused4":
+            step = make_sharded_fused_step(st, mesh, grid, k=4,
+                                           interpret=False)
+            assert step is not None
+        else:
+            step = make_sharded_step(st, mesh, grid)
+        run = make_runner(step, 4, jit=False)
+        compiled = jax.jit(run, donate_argnums=0).lower(
+            _fields(st, grid, sharding)).compile()
+    text = compiled.as_text()
+    assert "collective-permute" in text
+    _check(compiled, "collective-permute" if kind == "jnp"
+           else "tpu_custom_call")
     # per-device bytes: a quarter of the grid per chip
-    assert compiled.memory_analysis().argument_size_in_bytes == \
-        512 * 512 * 1024 * jnp.dtype(st.dtype).itemsize
+    assert compiled.memory_analysis().argument_size_in_bytes == shard
+    if kind == "raw":
+        assert not re.findall(
+            r"f32\[512,512,1024\]\{[^}]*\} copy(-start)?\(", text)
+        faces = 2 * (512 + 512) * 1024 * 4
+        assert compiled.memory_analysis().temp_size_in_bytes <= \
+            shard + faces

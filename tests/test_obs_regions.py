@@ -259,6 +259,16 @@ def _raw(name, grid):
     return make_raw_step(st, grid, interpret=True), _abstract(st, grid)
 
 
+def _sharded_raw(name, grid, mesh_shape):
+    from mpi_cuda_process_tpu.parallel.mesh import make_mesh
+    from mpi_cuda_process_tpu.parallel.stepper import make_sharded_raw_step
+
+    st = make_stencil(name)
+    step = make_sharded_raw_step(st, make_mesh(mesh_shape), grid,
+                                 interpret=True)
+    return step, _abstract(st, grid)
+
+
 def _compute(name, grid):
     from mpi_cuda_process_tpu.ops.pallas import make_pallas_compute
 
@@ -291,6 +301,8 @@ _KERNELS = {
     "rawstep_taps": lambda: _raw("heat3d", (16, 16, 128)),
     "rawstep_wave": lambda: _raw("wave3d", (16, 16, 128)),
     "rawstep_grayscott": lambda: _raw("grayscott3d", (16, 16, 128)),
+    "rawstep_sharded": lambda: _sharded_raw("heat3d", (32, 32, 128),
+                                            (2, 2, 1)),
     "zchunk_taps": lambda: _compute("heat3d", (16, 16, 128)),
     "zchunk_wave": lambda: _compute("wave3d", (16, 16, 128)),
     "whole2d": lambda: _compute("heat2d", (16, 128)),

@@ -131,3 +131,42 @@ def test_auto_path_names_the_table_decision():
     assert plan.auto_fuse_k(cfg, "cpu") is None
     assert plan.auto_fuse_k(dataclasses.replace(cfg, iters=6), "tpu") \
         is None
+
+
+def test_mesh_run_takes_the_sharded_raw_pass_where_it_builds(monkeypatch,
+                                                            caplog):
+    """On a TPU, an unfused --compute auto mesh run of heat3d takes the
+    sharded raw pass and says so; --compute jnp, --overlap and a periodic
+    run keep the jnp sharded step.  The unsharded choice at 1024^3 is
+    the one it was: heat3d fuses, wave3d takes the in-place raw pass."""
+    import logging
+
+    from mpi_cuda_process_tpu.ops.pallas import fused, rawstep
+
+    monkeypatch.setattr(plan.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(rawstep, "_interpret_default", lambda: True)
+    monkeypatch.setattr(fused, "_interpret_default", lambda: True)
+    cfg = RunConfig(stencil="heat3d", grid=(48, 32, 256), iters=8,
+                    log_every=8, mesh=(2, 2, 1))
+    assert plan.maybe_auto_fuse(cfg).fuse == 0
+    with caplog.at_level(logging.INFO, logger="mpi_cuda_process_tpu"):
+        _, step, _, _ = plan.build(cfg)
+    assert getattr(step, "_raw_kind", None) == "sharded"
+    assert any("compute: sharded whole-step raw Pallas kernel" in
+               r.getMessage() for r in caplog.records)
+    assert plan.budget_args(cfg, plan.make_cfg_stencil(cfg))["compute"] \
+        == "raw"
+    for other in ({"compute": "jnp"}, {"overlap": True},
+                  {"periodic": True}):
+        kept = dataclasses.replace(cfg, **other)
+        _, step, _, _ = plan.build(kept)
+        assert not hasattr(step, "_raw_kind"), other
+        assert plan.budget_args(kept, plan.make_cfg_stencil(kept))[
+            "compute"] == kept.compute
+    big = dict(grid=(1024, 1024, 1024), iters=64, log_every=16)
+    assert plan.maybe_auto_fuse(RunConfig(stencil="heat3d", **big)).fuse \
+        == 4
+    wave = RunConfig(stencil="wave3d", **big)
+    assert plan.maybe_auto_fuse(wave).fuse == 0
+    raw = plan.resolve_raw_step(wave, plan.make_cfg_stencil(wave))
+    assert raw is not None and not hasattr(raw, "_raw_kind")

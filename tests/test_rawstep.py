@@ -108,3 +108,71 @@ def test_runner_advances_a_carry_period_per_iteration(n):
                                                 for f in fields))
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# The sharded twin: one Pallas pass over the unpadded shard, faces as
+# operands.  Local blocks of 24 or 48 planes give three z-chunks a shard,
+# so the first, a middle and the last chunk all run.
+SHARDED_GRID = (48, 32, 256)
+SHARDED_MESHES = [(2, 2, 1), (2, 1, 1), (1, 2, 1)]
+
+
+@pytest.fixture(scope="module", params=SHARDED_MESHES,
+                ids=["x".join(map(str, m)) for m in SHARDED_MESHES])
+def sharded_pair(request):
+    """(sharded raw step, jnp sharded step, initial sharded state)."""
+    from mpi_cuda_process_tpu.parallel import stepper
+    from mpi_cuda_process_tpu.parallel.mesh import make_mesh
+
+    st = make_stencil("heat3d")
+    mesh = make_mesh(request.param)
+    raw = stepper.make_sharded_raw_step(st, mesh, SHARDED_GRID,
+                                        interpret=True)
+    assert raw is not None and raw._raw_kind == "sharded"
+    ref = stepper.make_sharded_step(st, mesh, SHARDED_GRID)
+    fields = stepper.shard_fields(
+        init_state(st, SHARDED_GRID, 3, 0.5, "random"), mesh, 3)
+    return raw, ref, fields
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 8])
+def test_sharded_raw_step_matches_the_jnp_sharded_step(sharded_pair, n):
+    """A lone pass, paired passes, an odd count and the 2x2 cell's count
+    of 8, through the runner: bit for bit the jnp sharded step's state,
+    and the global frame holds bc on every shard."""
+    raw, ref, fields = sharded_pair
+    assert raw._carry_period == 2 and raw._out_of_place
+    got = driver.make_runner(raw, n)(tuple(jnp.copy(f) for f in fields))
+    want = driver.make_runner(ref, n)(tuple(jnp.copy(f) for f in fields))
+    g, w = np.asarray(got[0]), np.asarray(want[0])
+    np.testing.assert_array_equal(g, w)
+    assert not np.array_equal(g, np.asarray(fields[0]))
+    bc = make_stencil("heat3d").bc_value[0]
+    for d in range(3):
+        for end in (0, -1):
+            np.testing.assert_array_equal(np.take(g, end, axis=d), bc)
+
+
+_DECLINES = [
+    ("periodic", "heat3d", {}, (2, 2, 1), SHARDED_GRID,
+     {"periodic": True}),
+    ("x-sharded", "heat3d", {}, (2, 1, 2), SHARDED_GRID, {}),
+    ("ensemble", "heat3d", {}, (2, 2, 1), SHARDED_GRID, {"ensemble": 2}),
+    ("heat3d27", "heat3d27", {}, (2, 2, 1), SHARDED_GRID, {}),
+    ("bf16", "heat3d", {"dtype": jnp.bfloat16}, (2, 2, 1), SHARDED_GRID,
+     {}),
+    # 7 planes a shard: no z-chunk of 2 or more tiles them
+    ("untileable", "heat3d", {}, (2, 1, 1), (14, 32, 256), {}),
+]
+
+
+@pytest.mark.parametrize("name,kw,mesh_shape,grid,opts",
+                         [c[1:] for c in _DECLINES],
+                         ids=[c[0] for c in _DECLINES])
+def test_sharded_raw_step_declines(name, kw, mesh_shape, grid, opts):
+    from mpi_cuda_process_tpu.parallel import stepper
+    from mpi_cuda_process_tpu.parallel.mesh import make_mesh
+
+    st = make_stencil(name, **kw)
+    assert stepper.make_sharded_raw_step(
+        st, make_mesh(mesh_shape), grid, interpret=True, **opts) is None
