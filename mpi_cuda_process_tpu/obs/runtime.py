@@ -118,6 +118,38 @@ def compile_events_seen() -> int:
         return int(sum(c + n for c, n, _ in _totals.values()))
 
 
+# ---------------------------------------------------- fused-pass records
+#
+# The work of every fused pass the program built (ops/pallas/fused.py
+# ``build_fused_call``, the auto-fuse probe's included): kernel name,
+# (bz, by) tiles, ``window_margin`` (wm, cells per z/y side of the window
+# each program computes beyond its core), k and the core shape.  A pass
+# computes k micro-steps over (bz + 2 wm) x (by + 2 wm) rows to write
+# bz x by: what it builds is what it computes, so the count needs no
+# traced code.
+_FUSED_PASS_KEEP = 256
+_fused_passes: "collections.deque[Dict[str, Any]]" = \
+    collections.deque(maxlen=_FUSED_PASS_KEEP)
+
+
+def record_fused_pass(name: str, tiles, window_margin: int, k: int,
+                      core_shape) -> None:
+    """Record one fused pass as it is built."""
+    bz, by = (int(t) for t in tiles)
+    rec = {"name": str(name), "tiles": (bz, by),
+           "window_margin": int(window_margin), "k": int(k),
+           "core_shape": tuple(int(n) for n in core_shape)}
+    with _counter_lock:
+        _fused_passes.append(rec)
+
+
+def fused_passes() -> List[Dict[str, Any]]:
+    """The fused passes built in this process, oldest first (the last
+    ``_FUSED_PASS_KEEP``)."""
+    with _counter_lock:
+        return [dict(r) for r in _fused_passes]
+
+
 def device_memory_stats() -> Dict[str, int]:
     """Whitelisted ``memory_stats()`` of device 0, or {} when unreported."""
     try:

@@ -1284,7 +1284,7 @@ def build_fused_call(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES,
             dimension_semantics=("arbitrary", "arbitrary")),
     )
-    return call, margin, nfields
+    return _recorded(padfree, k, (Z, Y, X), tiles, call, margin, nfields)
 
 
 def build_overlap_shell_calls(
@@ -1395,3 +1395,22 @@ def make_fused_step(
         return tuple(call(*args))
 
     return step_k
+
+
+def _recorded(padfree, k, core_shape, tiles, call, margin, nfields):
+    """``build_fused_call``'s result, once the pass it built is recorded
+    in the program's obs records (``obs/runtime.record_fused_pass``).
+
+    Each program of the pass writes a (bz, by, X) core from k micro-steps
+    over its whole window, ``wm`` cells wider on every z/y side: ``2m``
+    for the pad-free 9-block window, ``m`` for the padded one.  Kept
+    below every kernel so that recording shifts none of their source
+    lines, which the Mosaic bodies carry.
+    """
+    from ...obs.runtime import record_fused_pass
+
+    # the pallas_call's name in build_fused_call
+    name = "fused_padfree" if padfree else "fused_padded"
+    record_fused_pass(name, tiles, 2 * margin if padfree else margin, k,
+                      core_shape)
+    return call, margin, nfields

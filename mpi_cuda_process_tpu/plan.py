@@ -48,7 +48,10 @@ log = logging.getLogger("mpi_cuda_process_tpu")
 # the fused families below when cadences, the shape or the HBM budget
 # rule a fused pass out.
 #   wave3d: wave3d-1024.log16 (the in-place leapfrog pass at 1024^3)
-#   heat3d27, grayscott3d: no chip record
+#   heat3d27: heat3d27-1024.log16 with the fused pass declined: 28,602-
+#   28,819 Mcells/s (3 seeds; a 4 GiB copy before every step) against
+#   fused k=4's 48,327-48,354, so the fused pass stays
+#   grayscott3d: no chip record
 _RAW_WINS = {"heat3d27", "wave3d", "grayscott3d"}
 # The raw kernel at or above _CLIFF_CELLS cells, the jnp step below.
 #   heat3d, heat3d4th: no chip record (heat3d at 1024^3 takes the fused
@@ -65,7 +68,7 @@ _CLIFF_CELLS = 100_000_000
 #   heat3d: heat3d-1024.log16 and heat3d-1024.log4 (pad-free, k=4)
 #   wave3d: no chip record of the fused pass (at 1024^3 the budget
 #           declines it and wave3d-1024.log16 runs the raw pass)
-#   heat3d27: no chip record
+#   heat3d27: heat3d27-1024.log16 (pad-free, k=4; raw 40% slower)
 _AUTO_FUSE_K = {"heat3d": 4, "heat3d27": 4, "wave3d": 4}
 # bf16 temporal blocking: bf16's sublane tile (16) needs k=8 for halo-1
 # stencils (fused._sublane).  Empty, no chip record: bf16 runs keep the

@@ -120,6 +120,32 @@ def test_fused_heat3d_1024_runner_copies_no_state(one_chip, n):
         assert not copies
 
 
+@pytest.mark.slow  # about 75 s: Mosaic compiles the 27-point k=4 pass
+def test_fused_heat3d27_1024_runner_copies_no_state(one_chip):
+    """BASELINE config 4's per-chip share, heat3d27 1024^3 f32, as
+    --compute auto builds it for 16 steps a chunk: four pad-free k=4
+    passes, two a scan iteration, ping-ponged between the donated state
+    and one temporary.  No whole-state copy; the state and that
+    temporary are the peak."""
+    from mpi_cuda_process_tpu.ops.pallas.fused import (
+        make_fused_step, prefer_padfree,
+    )
+
+    st = make_stencil("heat3d27")
+    grid = (1024, 1024, 1024)
+    assert prefer_padfree(st, grid)
+    step = make_fused_step(st, grid, 4, interpret=False, padfree=True)
+    assert step._carry_period == 2
+    compiled = make_runner(step, 4).lower(
+        _fields(st, grid, one_chip)).compile()
+    text = compiled.as_text()
+    assert _peak_bytes(compiled) <= 8 * 1024**3 + 2**20, \
+        _peak_bytes(compiled)
+    state = r"f32\[1024,1024,1024\]\{[^}]*\} "
+    assert len(re.findall(state + r"custom-call\(", text)) == 2
+    assert not re.findall(state + r"copy(-start)?\(", text)
+
+
 def test_raw_wave3d_512(one_chip):
     from mpi_cuda_process_tpu.ops.pallas.rawstep import make_raw_step
 
